@@ -64,10 +64,10 @@ def argmin_structure(n: int, m: int, bn: int = 256) -> dict:
     padded-tail fraction the last block masks out.  Kept measured here
     so the kernel cannot bit-rot while it waits to be plugged into the
     batch scheduling policies."""
-    bn_eff = min(bn, n)
+    bn_eff = n if n <= bn else -(-bn // 8) * 8    # whole axis or 8-row tiles
     pad = (-n) % bn_eff
     n_blocks = (n + pad) // bn_eff
-    vmem = bn_eff * m * (4 + 1)           # f32 values + bool mask block
+    vmem = bn_eff * m * (4 + 4)           # f32 values + int32 mask block
     return {
         "tasks": n, "machines": m, "block_n": bn_eff,
         "grid_steps": n_blocks,
@@ -81,15 +81,17 @@ def fused_dispatch_structure(n: int, m: int, t: int, bn: int = 256) -> dict:
     (EXPERIMENTS.md §Kernels): the jnp path materializes three (N, M)
     intermediates — completion matrix, bool pair mask, BIG-masked copy
     (write + read each) — on top of the hoisted eet_nm read; the fused
-    kernel streams the O(N + T·M) inputs and writes O(1) scalars, with
-    the (T, M) type-level EET table re-read once per grid step."""
-    bn_eff = min(bn, n)
+    kernel streams the O(N + T·M) inputs (int32 masks, tasks padded to
+    whole 128-lane blocks) and writes O(1) scalars, with the (T, M)
+    type-level EET table counted once per grid step (an upper bound:
+    its block index never changes)."""
+    bn_eff = n if n <= bn else -(-bn // 128) * 128
     pad = (-n) % bn_eff
     n_blocks = (n + pad) // bn_eff
     jnp_bytes = n * m * (4 + 8 + 2 + 8)
     fused_bytes = (n_blocks * t * m * 4      # (T, M) table per grid step
-                   + n * (4 + 1)             # type_id + in_batch stream
-                   + m * (4 + 1)             # avail + room, read once
+                   + (n + pad) * (4 + 4)     # type_id + in_batch stream
+                   + m * (4 + 4)             # avail + room, read once
                    + 12)                     # scalar outputs
     return {
         "tasks": n, "machines": m, "types": t, "grid_steps": n_blocks,

@@ -103,8 +103,9 @@ def compare_runs(prev: dict, cur: dict,
 def _compile_cache_probe() -> dict:
     """Enable jax's persistent compilation cache and measure it.
 
-    Turns on ``jax_compilation_cache_dir`` (under ``results/jax_cache``,
-    via ``experiment.enable_compilation_cache``), then times one tiny
+    Turns on ``jax_compilation_cache_dir`` (``JAX_COMPILATION_CACHE_DIR``
+    where set, else ``results/jax_cache`` under the checkout, via
+    ``experiment.enable_compilation_cache``), then times one tiny
     canonical sweep twice: the first call pays trace + compile ("cold" —
     on a re-run of this process the XLA compile is served from disk, so
     this number is the cache's measured benefit run-over-run), the
@@ -118,7 +119,7 @@ def _compile_cache_probe() -> dict:
     from repro.launch.sim import make_replicas
 
     cache_dir = XP.enable_compilation_cache()
-    info: dict = {"dir": cache_dir or "disabled"}
+    info: dict = {"dir": cache_dir}
     with TL.span("compile_cache", dir=info["dir"]) as sp:
         probe = make_replicas(2, 16, 4, seed=0) + (None, None, None)
         sweep = XP.compile_sweep()

@@ -48,6 +48,7 @@ engine↔oracle parity suite covers learned policies too.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -194,14 +195,19 @@ def machine_features(state: S.SimState, view: P.SchedView) -> jnp.ndarray:
     return feats.astype(jnp.float32)
 
 
+# f32 matmuls at full precision: a TPU's default passes bf16 operands,
+# which would split the engine from its exact-f32 numpy mirror below
+_dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
+
 def mlp_scores(params: MLPParams, feats: jnp.ndarray) -> jnp.ndarray:
     """(M,) scores; lower = better machine.  ReLU hidden layer."""
-    hid = jnp.maximum(feats @ params.w1 + params.b1, 0.0)
-    return hid @ params.w2 + params.b2
+    hid = jnp.maximum(_dot(feats, params.w1) + params.b1, 0.0)
+    return _dot(hid, params.w2) + params.b2
 
 
 def linear_scores(params: LinearParams, feats: jnp.ndarray) -> jnp.ndarray:
-    return feats @ params.w
+    return _dot(feats, params.w)
 
 
 # --------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
+On a CPU backend the kernels execute in ``interpret=True`` mode —
 the kernel body runs in Python against the same BlockSpec tiling, which is
 what the correctness tests validate.  On a real TPU backend the same calls
 compile to Mosaic.  ``interpret`` can be forced either way for tests.
@@ -10,18 +10,14 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.grouped_matmul import grouped_matmul as _gmm
+from repro.kernels.sched_argmin import default_interpret as _default_interpret
 from repro.kernels.sched_argmin import fused_maxmin as _maxmin
 from repro.kernels.sched_argmin import fused_minmin as _minmin
 from repro.kernels.sched_argmin import masked_argmin as _argmin
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "softcap",

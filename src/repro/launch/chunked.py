@@ -11,8 +11,9 @@ scenario grids ROADMAP item 3 asks for.  This module is the scale path
             chunk's draws are bitwise those of the monolithic grid) and
             executed *through the existing cached executable*
             (:func:`experiment.compile_sweep`), wrapped in a jitted step
-            with **donated** inputs (``jax.jit(..., donate_argnums)``)
-            so chunk N+1 reuses chunk N's device buffers.
+            that **donates** the running aggregate
+            (``jax.jit(..., donate_argnums=0)``), so chunk N+1's fold
+            writes in place of chunk N's.
   reduce    the step folds each chunk's per-replica metrics into a
             ``SweepAgg`` pytree on device — per report column and per
             policy: count, min, max, a log-bucket histogram on
@@ -46,9 +47,8 @@ from __future__ import annotations
 import contextlib
 import math
 import time
-import warnings
-from dataclasses import dataclass, field
-from typing import Any, Callable, NamedTuple
+from dataclasses import dataclass
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -307,10 +307,11 @@ def _compile_chunk_step(params, aspec: ME.MetricsSpec, streaming: bool,
     wrapped sweep IS the ``compile_sweep`` executable, inlined).
 
     ``step(cols, pol_idx, args, policy_params) -> (cols', metrics|None,
-    token)`` — ``cols``/``pol_idx``/``args`` are donated so each chunk
-    reuses the previous chunk's device memory; ``token`` is a fresh tiny
-    array (not aliased to ``cols'``) the driver can block on after the
-    accumulator has been donated onward."""
+    token)`` — ``cols`` is donated and aliases ``cols'`` (the only
+    output of matching shape, so the only donation XLA can use); the
+    chunk inputs are freed when the driver drops them.  ``token`` is a
+    fresh tiny array (not aliased to ``cols'``) the driver can block on
+    after the accumulator has been donated onward."""
     key = ("chunked", params, aspec, streaming, keep)
     fn = X._EXEC_CACHE.get(key)
     if fn is not None:
@@ -326,7 +327,7 @@ def _compile_chunk_step(params, aspec: ME.MetricsSpec, streaming: bool,
         token = next(iter(out.values())).count.sum()
         return out, (m if keep else None), token
 
-    fn = jax.jit(step, donate_argnums=(0, 1, 2))
+    fn = jax.jit(step, donate_argnums=0)
     X._EXEC_CACHE[key] = fn
     return fn
 
@@ -456,13 +457,8 @@ def run_chunked_experiment(spec, chunk: int, *, mesh=None,
             cur = materialize(0, min(chunk, n_rep))
         stats.normalize_s += time.perf_counter() - t0
         cols = {}
-        with warnings.catch_warnings(), \
-                (jax.profiler.trace(profile_dir) if profile_dir
-                 else contextlib.nullcontext()):
-            # CPU backends ignore buffer donation and say so; the
-            # donation is structural (live on GPU/TPU), not load-bearing
-            warnings.filterwarnings(
-                "ignore", message=".*[Dd]onat")
+        with (jax.profiler.trace(profile_dir) if profile_dir
+              else contextlib.nullcontext()):
             for c in range(n_chunks):
                 if c == 0:
                     keys = jax.eval_shape(

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # a compile tool: never takes a chip
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

@@ -567,35 +567,41 @@ _EXEC_CACHE: dict[E.SimParams, Any] = {}
 _CACHE_STATS = {"hits": 0, "misses": 0, "retraces": 0}
 
 
+#: the checkout's own cache directory, fixed by this file's location (the
+#: path is part of jax's cache key, so it must not follow the cwd)
+DEFAULT_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir,
+    os.pardir, "results", "jax_cache"))
+
+
 def persistent_cache_dir() -> str | None:
     """The configured ``jax_compilation_cache_dir`` (None = disabled)."""
-    try:
-        return jax.config.jax_compilation_cache_dir
-    except AttributeError:
-        return None
+    return jax.config.jax_compilation_cache_dir
 
 
-def enable_compilation_cache(path: str | None = None) -> str | None:
-    """Turn on jax's persistent compilation cache under ``results/``.
+def enable_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns its directory.
 
     Compiled executables (every ``compile_sweep`` specialization, the
     streaming twin, the chunked driver) are serialized to disk and
-    reloaded by later *processes*: a bench re-run or CI shard pays jax's
-    trace time but skips the XLA compile — the cold-vs-warm compile
-    times land as telemetry span attrs (docs/experiments.md §Compilation
-    cache).  Returns the cache directory, or None when the knob is
-    unavailable on this jax build (the engine runs unchanged).
+    reloaded by later *processes*: a bench re-run pays jax's trace time
+    but skips the XLA compile (docs/experiments.md §Compilation cache).
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it:
+    that directory is returned and no config is touched.  Otherwise the
+    cache goes to :data:`DEFAULT_CACHE_DIR` (``results/jax_cache`` under
+    the checkout) with the size/time thresholds zeroed, so every sweep is
+    cached.  A failure to create or configure it raises.
     """
-    path = path or os.path.join("results", "jax_cache")
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # cache every entry: the sweeps worth caching are small but many
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        return None
-    return path
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    os.makedirs(DEFAULT_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    # cache every entry: the sweeps worth caching are small but many
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return DEFAULT_CACHE_DIR
 
 
 def _count_retrace(vf):

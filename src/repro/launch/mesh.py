@@ -14,16 +14,10 @@ Topology intent (TPU v5e):
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # older jax: meshes are Auto-typed already
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

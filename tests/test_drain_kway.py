@@ -189,7 +189,8 @@ def test_fused_start_pick_matches_oracle(n, m):
     machine = jnp.asarray(rng.integers(-1, m, n).astype(np.int32))
     seq = jnp.asarray(rng.integers(0, 1 << 20, n).astype(np.int32))
     pick, has = K.fused_start_pick(status, machine, seq, m,
-                                   in_mq=S.IN_MQ, interpret=True)
+                                   in_mq=S.IN_MQ, block_n=128,
+                                   interpret=True)
     rpick, rhas = KREF.fused_start_pick_ref(status, machine, seq, m,
                                             in_mq=S.IN_MQ)
     np.testing.assert_array_equal(np.asarray(pick), np.asarray(rpick))
@@ -213,7 +214,8 @@ def test_fused_event_bounds_matches_oracle(n):
     deadline = jnp.asarray(rng.uniform(0, 200, n).astype(np.float32))
     t_arr, t_dl = K.fused_event_bounds(
         status, arrival, deadline, not_arrived=S.NOT_ARRIVED,
-        live_lo=S.IN_BATCH, live_hi=S.RUNNING, interpret=True)
+        live_lo=S.IN_BATCH, live_hi=S.RUNNING, block_n=128,
+        interpret=True)
     r_arr, r_dl = KREF.fused_event_bounds_ref(
         status, arrival, deadline, not_arrived=S.NOT_ARRIVED,
         live_lo=S.IN_BATCH, live_hi=S.RUNNING)
@@ -223,5 +225,5 @@ def test_fused_event_bounds_matches_oracle(n):
     t_arr, t_dl = K.fused_event_bounds(
         jnp.full((n,), 7, jnp.int32), arrival, deadline,
         not_arrived=S.NOT_ARRIVED, live_lo=S.IN_BATCH,
-        live_hi=S.RUNNING, interpret=True)
+        live_hi=S.RUNNING, block_n=128, interpret=True)
     assert not np.isfinite(float(t_arr)) and not np.isfinite(float(t_dl))

@@ -229,6 +229,43 @@ def test_shared_executable_across_modes():
         assert X.run_experiment(s).metrics["completed"].shape == (2,)
 
 
+# -- persistent compilation cache -------------------------------------------
+
+_CACHE_KNOBS = ("jax_compilation_cache_dir",
+                "jax_persistent_cache_min_entry_size_bytes",
+                "jax_persistent_cache_min_compile_time_secs")
+
+
+@pytest.fixture
+def cache_config():
+    """Snapshot the cache knobs and restore them, so enabling the cache
+    here does not make every later compile in this worker write to it."""
+    saved = {k: getattr(jax.config, k) for k in _CACHE_KNOBS}
+    yield saved
+    for k, v in saved.items():
+        jax.config.update(k, v)
+
+
+def test_compilation_cache_honours_env(cache_config, monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert X.enable_compilation_cache() == str(tmp_path)
+    assert {k: getattr(jax.config, k) for k in _CACHE_KNOBS} == cache_config
+
+
+def test_compilation_cache_default_is_fixed_in_checkout(
+        cache_config, monkeypatch, tmp_path):
+    import os
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    paths = []
+    for cwd in (tmp_path, os.path.dirname(__file__)):
+        monkeypatch.chdir(cwd)
+        paths.append(X.enable_compilation_cache())
+    assert paths[0] == paths[1] == X.DEFAULT_CACHE_DIR
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert paths[0] == os.path.join(root, "results", "jax_cache")
+    assert jax.config.jax_compilation_cache_dir == paths[0]
+
+
 # -- execution: results, flags, sharding ------------------------------------
 
 

@@ -9,31 +9,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.launch.hlo_cost import analyze, parse_module, shape_elems_bytes
-
-# Environment gates (pre-existing failures since the seed, skip-gated so
-# tier-1 tracks real regressions): jax < 0.5 returns a LIST from
-# ``Compiled.cost_analysis()`` and emits while-loop HLO text the
-# trip-count walker undercounts; ``jax.sharding.AxisType`` (needed by
-# the multi-device subprocess test) only exists on jax >= 0.5.
-_JAX_VER = tuple(int(x) for x in jax.__version__.split(".")[:2])
-try:
-    from jax.sharding import AxisType as _AxisType
-except ImportError:
-    _AxisType = None
-
-_NEEDS_JAX_05 = pytest.mark.skipif(
-    _JAX_VER < (0, 5),
-    reason=f"jax {jax.__version__}: cost_analysis()/while-loop HLO "
-           "text predate the walker's cost model (known env failure "
-           "since seed; needs jax>=0.5)")
-_NEEDS_AXISTYPE = pytest.mark.skipif(
-    _AxisType is None,
-    reason=f"jax {jax.__version__} has no jax.sharding.AxisType; the "
-           "forced-multi-device subprocess cannot build a typed mesh "
-           "(known env failure since seed; needs jax>=0.5)")
 
 W = jnp.zeros((256, 256), jnp.float32)
 X = jax.ShapeDtypeStruct((256, 256), jnp.float32)
@@ -43,7 +20,6 @@ def _compiled(f):
     return jax.jit(f).lower(X).compile()
 
 
-@_NEEDS_JAX_05
 def test_xla_undercounts_scan():
     """Pin the XLA behaviour this module exists to fix."""
     def f_scan(x):
@@ -57,7 +33,6 @@ def test_xla_undercounts_scan():
     assert scan_flops < 2 * once_flops    # ~1x, NOT ~10x
 
 
-@_NEEDS_JAX_05
 def test_scan_flops_match_unroll():
     def f_scan(x):
         return jax.lax.scan(lambda c, _: (jnp.tanh(c @ W), None), x, None,
@@ -76,7 +51,6 @@ def test_scan_flops_match_unroll():
     np.testing.assert_allclose(a_u.flops, want, rtol=0.02)
 
 
-@_NEEDS_JAX_05
 def test_nested_scan_multiplies():
     def f(x):
         def outer(c, _):
@@ -101,7 +75,6 @@ def test_dynamic_while_reported_unknown():
     assert a.unknown_loops >= 1
 
 
-@_NEEDS_AXISTYPE
 def test_collectives_inside_scan_multiply():
     import os
     import subprocess

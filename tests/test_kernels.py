@@ -137,7 +137,7 @@ def test_masked_argmin_padded_tail_vs_jnp_oracle(n, bn):
 
 def test_masked_argmin_min_in_tail_block():
     """The global minimum sits in the ragged final block's valid rows —
-    the carried (min, argmin) SMEM scratch must be updated by the last
+    the carried (min, argmin) scratch must be updated by the last
     grid step, not just initialized by the first."""
     vals = jnp.full((70, 3), 5.0).at[69, 2].set(0.5)
     mask = jnp.ones((70, 3), bool)

@@ -50,14 +50,14 @@ def _fused_instance(seed, n, m, t):
     return avail, in_batch, room, type_id, eet_m
 
 
-def _assert_minmin(args, bn=8):
+def _assert_minmin(args, bn=128):
     ki, kv = ops.fused_minmin(*args, block_n=bn, interpret=True)
     ri, rv = ref.fused_minmin_ref(*args)
     assert int(ki) == int(ri)
     assert float(kv) == float(rv)
 
 
-def _assert_maxmin(args, bn=8):
+def _assert_maxmin(args, bn=128):
     kt, km, ks = ops.fused_maxmin(*args, block_n=bn, interpret=True)
     rt, rm, rs = ref.fused_maxmin_ref(*args)
     assert (int(kt), int(km)) == (int(rt), int(rm))
@@ -182,12 +182,12 @@ def test_vmapped_kernel_matches_per_replica():
 # fused min-min / max-min
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize("seed,n,m,t,bn", [
-    (0, 24, 4, 3, 8),       # engine-shaped
-    (1, 33, 6, 5, 16),      # ragged tail
-    (2, 7, 2, 2, 8),        # tiny
-    (3, 64, 8, 4, 16),      # multi-block exact fit
+    (0, 24, 4, 3, 128),     # engine-shaped, one whole-axis block
+    (1, 300, 6, 5, 128),    # ragged tail
+    (2, 7, 2, 2, 128),      # tiny
+    (3, 512, 8, 4, 128),    # multi-block exact fit
     (4, 129, 5, 3, 64),     # ragged across blocks
-    (5, 1, 1, 1, 8),        # degenerate
+    (5, 1, 1, 1, 128),      # degenerate
 ])
 def test_fused_pair_kernels_match_oracle(seed, n, m, t, bn):
     args = _fused_instance(seed, n, m, t)
@@ -196,35 +196,35 @@ def test_fused_pair_kernels_match_oracle(seed, n, m, t, bn):
 
 
 def test_fused_empty_batch_sentinel():
-    avail, _, room, tid, eet_m = _fused_instance(6, 16, 4, 2)
-    args = (avail, jnp.zeros(16, bool), room, tid, eet_m)
-    ki, kv = ops.fused_minmin(*args, block_n=8, interpret=True)
+    avail, _, room, tid, eet_m = _fused_instance(6, 300, 4, 2)
+    args = (avail, jnp.zeros(300, bool), room, tid, eet_m)
+    ki, kv = ops.fused_minmin(*args, block_n=128, interpret=True)
     assert (int(ki), float(kv)) == (-1, BIG)
-    kt, km, ks = ops.fused_maxmin(*args, block_n=8, interpret=True)
+    kt, km, ks = ops.fused_maxmin(*args, block_n=128, interpret=True)
     assert (int(kt), int(km)) == (-1, -1)
     _assert_minmin(args)
     _assert_maxmin(args)
 
 
 def test_fused_no_room_sentinel():
-    avail, inb, _, tid, eet_m = _fused_instance(7, 16, 4, 2)
+    avail, inb, _, tid, eet_m = _fused_instance(7, 300, 4, 2)
     args = (avail, inb, jnp.zeros(4, bool), tid, eet_m)
-    ki, _ = ops.fused_minmin(*args, block_n=8, interpret=True)
-    kt, km, _ = ops.fused_maxmin(*args, block_n=8, interpret=True)
+    ki, _ = ops.fused_minmin(*args, block_n=128, interpret=True)
+    kt, km, _ = ops.fused_maxmin(*args, block_n=128, interpret=True)
     assert int(ki) == int(kt) == int(km) == -1
     _assert_minmin(args)
     _assert_maxmin(args)
 
 
 def test_fused_single_valid_pair():
-    avail, _, _, tid, eet_m = _fused_instance(8, 20, 5, 3)
-    inb = jnp.zeros(20, bool).at[17].set(True)
+    avail, _, _, tid, eet_m = _fused_instance(8, 300, 5, 3)
+    inb = jnp.zeros(300, bool).at[217].set(True)
     room = jnp.zeros(5, bool).at[3].set(True)
     args = (avail, inb, room, tid, eet_m)
-    ki, _ = ops.fused_minmin(*args, block_n=8, interpret=True)
-    assert int(ki) == 17 * 5 + 3
-    kt, km, _ = ops.fused_maxmin(*args, block_n=8, interpret=True)
-    assert (int(kt), int(km)) == (17, 3)
+    ki, _ = ops.fused_minmin(*args, block_n=128, interpret=True)
+    assert int(ki) == 217 * 5 + 3
+    kt, km, _ = ops.fused_maxmin(*args, block_n=128, interpret=True)
+    assert (int(kt), int(km)) == (217, 3)
     _assert_minmin(args)
     _assert_maxmin(args)
 
@@ -233,23 +233,23 @@ def test_fused_duplicate_completions_tie_break():
     """Identical EET rows + equal availability => every pair ties; both
     kernels must pick jnp's first index (task-major for min-min; for
     max-min the first queued task and its first machine)."""
-    n, m = 26, 4
+    n, m = 300, 4
     avail = jnp.zeros(m)
     inb = jnp.ones(n, bool).at[0].set(False)     # first queued task is #1
     room = jnp.ones(m, bool)
     tid = jnp.zeros(n, jnp.int32)
     eet_m = jnp.ones((2, m))
     args = (avail, inb, room, tid, eet_m)
-    ki, _ = ops.fused_minmin(*args, block_n=8, interpret=True)
+    ki, _ = ops.fused_minmin(*args, block_n=128, interpret=True)
     assert int(ki) == 1 * m + 0
-    kt, km, _ = ops.fused_maxmin(*args, block_n=8, interpret=True)
+    kt, km, _ = ops.fused_maxmin(*args, block_n=128, interpret=True)
     assert (int(kt), int(km)) == (1, 0)
     _assert_minmin(args)
     _assert_maxmin(args)
 
 
 def test_fused_large_values_match_oracle():
-    avail, inb, room, tid, _ = _fused_instance(9, 18, 3, 2)
+    avail, inb, room, tid, _ = _fused_instance(9, 300, 3, 2)
     eet_m = jnp.asarray([[1e28, 2e30, 5.0], [np.inf, 0.25, 1e29]],
                         jnp.float32)
     args = (avail, inb, room, tid, eet_m)
@@ -258,12 +258,12 @@ def test_fused_large_values_match_oracle():
 
 
 def test_fused_vmapped_matches_per_replica():
-    B, n, m, t = 4, 20, 5, 3
+    B, n, m, t = 4, 300, 5, 3
     rng = np.random.default_rng(12)
     stack = [_fused_instance(100 + i, n, m, t) for i in range(B)]
     batched = jax.tree.map(lambda *xs: jnp.stack(xs), *stack)
     fi, fv = jax.vmap(
-        lambda *a: ops.fused_minmin(*a, block_n=8, interpret=True)
+        lambda *a: ops.fused_minmin(*a, block_n=128, interpret=True)
     )(*batched)
     for i in range(B):
         ri, rv = ref.fused_minmin_ref(*stack[i])
@@ -290,9 +290,9 @@ def test_property_masked_argmin(seed, n, m, bn, p):
 
 
 @settings(max_examples=40, deadline=None)
-@given(seed=st.integers(0, 10_000), n=st.integers(1, 60),
+@given(seed=st.integers(0, 10_000), n=st.integers(1, 400),
        m=st.integers(1, 10), t=st.integers(1, 5),
-       bn=st.sampled_from([4, 8, 16, 256]))
+       bn=st.sampled_from([128, 256]))
 def test_property_fused_pair_kernels(seed, n, m, t, bn):
     args = _fused_instance(seed, n, m, t)
     _assert_minmin(args, bn)

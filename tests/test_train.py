@@ -29,12 +29,6 @@ CFG = get_arch("qwen2-1.5b").tiny()
 SHAPE = ShapeConfig("t", "train", 32, 4)
 MOPTS = ModelOptions(dtype=jnp.float32, remat=False)
 
-try:
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # jax < 0.5
-    _AxisType = None
-
-
 def make_arts(mesh, **kw):
     return LT.build_train_artifacts(CFG, SHAPE, mesh, mopts=MOPTS,
                                     ocfg=AdamWConfig(lr=1e-2), **kw)
@@ -145,11 +139,6 @@ def test_elastic_checkpoint_shape_independence(tmp_path):
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    _AxisType is None,
-    reason=f"jax {jax.__version__} has no jax.sharding.AxisType; the "
-           "8-fake-device subprocess cannot build the typed (pod, data, "
-           "model) mesh (known env failure since seed; needs jax>=0.5)")
 def test_compressed_grads_match(tmp_path):
     """int8 cross-pod train step ~= uncompressed step (subprocess with 8
     fake devices so this process keeps 1 device)."""
