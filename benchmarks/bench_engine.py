@@ -23,9 +23,7 @@ the vectorized engine makes *simulated* studies cheap at scale:
   T11. the chunked Monte-Carlo driver (launch/chunked.py) scales flat:
       per-replica cost at R=100k stays within 1.3x of R=1k (donated
       buffers + device-side SweepAgg reduction keep host and device
-      memory O(chunk)), and the async double-buffer actually overlaps —
-      host normalize time hidden behind device execution is > 0
-      (docs/scaling.md);
+      memory O(chunk)) (docs/scaling.md);
   T12. the overhauled drain hot loop (carried machine-available vector,
       incremental queue counters, zero-trip empty drains) schedules a
       dense N=512 batch instance >= 1.5x faster per replica than the
@@ -235,9 +233,8 @@ def time_chunked_sweep(n_small: int, n_big: int, chunk: int = 250):
     ``run_experiment(spec, chunk=...)`` — the donated double-buffered
     driver folding the device-side SweepAgg — after a warm run that pays
     the chunk-shaped compilation.  Returns the two per-replica wall
-    times plus the big run's :class:`chunked.ChunkedStats` (whose
-    ``overlap_s`` proves host normalize was hidden behind device
-    execution).
+    times plus the big run's :class:`chunked.ChunkedStats` (the host
+    normalize / dispatch / sync split).
     """
     spec = XP.ExperimentSpec(
         n_small, XP.FleetAxis(4), XP.WorkloadAxis(16),
@@ -317,60 +314,6 @@ def time_hot_loop(n_tasks: int, n_machines: int = N_MACHINES,
             res = fn(tt, mt, tb)
         jax.block_until_ready(res)
         out[label] = (time.perf_counter() - t0) / reps / n_replicas
-    return out
-
-
-def phase_breakdown(n_tasks: int = 512, n_machines: int = N_MACHINES,
-                    n_replicas: int = 4, reps: int = 300) -> dict:
-    """Measured per-event phase costs (docs/engine_perf.md §breakdown).
-
-    Times each event phase standalone (jit + vmap over the replica
-    axis) on the post-drain dense state — every task queued or running,
-    the steady state of the batch regime.  Values are microseconds per
-    call for the whole replica batch; each includes the per-call jit
-    dispatch overhead (~tens of us on CPU), so compare differences, not
-    absolutes — inside ``run_sim``'s while loop the phases fuse into
-    one XLA computation.
-    """
-    from repro.core import state as S
-    lcap = max(4, -(-n_tasks // n_machines))
-    tt, mt, tb, _ = _dense_batch_inputs(n_replicas, n_tasks, n_machines)
-    pid_const = jnp.int32(P.POLICY_IDS["mct"])
-    params = E.SimParams(lcap=lcap, drain_k=1)
-
-    @jax.jit
-    @jax.vmap
-    def mk(tasks, mtype, table):
-        st = S.init_state(tasks, mtype, None, None)
-        st = E._arrivals(st, params.qcap)
-        st = E._drain(st, table, pid_const, params)
-        st = E._start_tasks(st, table)
-        return st
-    st0 = mk(tt, mt, tb)
-    jax.block_until_ready(st0)
-
-    phases = {
-        "next_event_time": lambda st, table: E._next_event_time(st),
-        "completions": lambda st, table: E._completions(st, table),
-        "arrivals": lambda st, table: E._arrivals(st, params.qcap),
-        "deadline_drops": lambda st, table: E._deadline_drops(st, table),
-        "drain_no_work": lambda st, table: E._drain(st, table, pid_const,
-                                                    params),
-        "start_tasks": lambda st, table: E._start_tasks(st, table),
-    }
-    out = {"n_tasks": n_tasks, "n_machines": n_machines,
-           "n_replicas": n_replicas, "unit": "us_per_call",
-           "phases_us": {}}
-    for name, f in phases.items():
-        g = jax.jit(jax.vmap(f))
-        res = g(st0, tb)
-        jax.block_until_ready(res)
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            res = g(st0, tb)
-        jax.block_until_ready(res)
-        out["phases_us"][name] = round(
-            (time.perf_counter() - t0) / reps * 1e6, 1)
     return out
 
 
@@ -492,8 +435,7 @@ def run(out_dir=None, smoke: bool = False) -> dict:
                      "replicas_per_s": round(1 / per, 1)})
 
     # chunked Monte-Carlo driver: the replica axis grows 10-100x at a
-    # fixed chunk; per-replica cost must stay flat and the async driver
-    # must actually overlap normalize with device execution (T11)
+    # fixed chunk; per-replica cost must stay flat (T11)
     chunk_small, chunk_big = 1000, (10_000 if smoke else 100_000)
     chunked_small, chunked_big, chunked_stats = time_chunked_sweep(
         chunk_small, chunk_big)
@@ -514,14 +456,8 @@ def run(out_dir=None, smoke: bool = False) -> dict:
                      "per_replica_ms": round(per * 1e3, 3),
                      "replicas_per_s": round(1 / per, 1)})
 
-    # per-event phase cost breakdown — uploaded next to the run ledger
-    # (docs/engine_perf.md; CI artifact)
-    breakdown = phase_breakdown(hot_n, reps=100 if smoke else 300)
-    breakdown["hot_loop"] = {
-        k: round(v * 1e3, 3) for k, v in hot.items()}
-    breakdown["hot_loop"]["speedup_vs_legacy"] = round(
-        hot["legacy"] / hot["hot"], 2)
-    save_result("phase_breakdown", breakdown, out_dir)
+    hot_loop = {k: round(v * 1e3, 3) for k, v in hot.items()}
+    hot_loop["speedup_vs_legacy"] = round(hot["legacy"] / hot["hot"], 2)
 
     checks = {
         "T1_jit_beats_python_ref": bool(per_replica_1 < ref_per_replica),
@@ -543,13 +479,11 @@ def run(out_dir=None, smoke: bool = False) -> dict:
         "T10_metrics_overhead_bounded": bool(
             metrics_per * 1e3 < 2 * static_same_n),
         "T11_chunked_per_replica_flat": bool(
-            chunked_big < 1.3 * chunked_small
-            and chunked_stats.overlap_s > 0),
+            chunked_big < 1.3 * chunked_small),
         "T12_hot_loop_speedup": bool(hot["legacy"] >= 1.5 * hot["hot"]),
     }
     payload = {"rows": rows,
-               "hot_loop": breakdown["hot_loop"],
-               "phase_breakdown_us": breakdown["phases_us"],
+               "hot_loop": hot_loop,
                "chunked": {
                    "chunk": 250,
                    "n_small": chunk_small,
@@ -557,8 +491,8 @@ def run(out_dir=None, smoke: bool = False) -> dict:
                    "per_replica_small_ms": round(chunked_small * 1e3, 3),
                    "per_replica_big_ms": round(chunked_big * 1e3, 3),
                    "drift": round(chunked_big / chunked_small, 3),
-                   "overlap_s": round(chunked_stats.overlap_s, 3),
-                   "overlap_frac": round(chunked_stats.overlap_frac, 3)},
+                   "normalize_s": round(chunked_stats.normalize_s, 3),
+                   "sync_s": round(chunked_stats.sync_s, 3)},
                "ref_per_replica_ms": round(ref_per_replica * 1e3, 2),
                "experiment_cache": {
                    "first_s": round(cache_first, 4),

@@ -147,7 +147,8 @@ def run_grid(spec, clock: CompileClock, *, mesh=None, chunk: int = 0,
              f"replicas_per_s={spec.n_replicas / wall} "
              f"replicas_per_s_after_compile="
              f"{spec.n_replicas / max(wall - compile_s, 1e-9)} "
-             f"chunk={chunk} overlap_frac={res.chunked.overlap_frac}")
+             f"chunk={chunk} normalize_s={res.chunked.normalize_s} "
+             f"sync_s={res.chunked.sync_s}")
     log(tag, f"terminal tasks {got} of {want}; completion_rate mean "
              f"{agg.mean('completion_rate')}")
     if got != want or agg.count() != spec.n_replicas:

@@ -113,6 +113,10 @@ class SimParams(NamedTuple):
 # --------------------------------------------------------------------------
 # Event phases
 # --------------------------------------------------------------------------
+# Each phase runs under a ``jax.named_scope`` of its name, so the HLO
+# ``op_name`` of every op (and so a device trace) names the phase it
+# belongs to; a scope is compile-time metadata only.
+@jax.named_scope("completions")
 def _completions(st: S.SimState, tb: S.StaticTables) -> S.SimState:
     mach, tasks = st.machines, st.tasks
     n = tasks.arrival.shape[0]
@@ -143,6 +147,7 @@ def _completions(st: S.SimState, tb: S.StaticTables) -> S.SimState:
                    n_live=st.n_live - jnp.sum(done_m, dtype=jnp.int32))
 
 
+@jax.named_scope("availability")
 def _availability(st: S.SimState, tb: S.StaticTables,
                   dyn: S.MachineDynamics) -> S.SimState:
     """Dynamic-scenario phase: evict work from machines that are down.
@@ -217,6 +222,7 @@ def _availability(st: S.SimState, tb: S.StaticTables,
                    n_batch=st.n_batch + requeues)
 
 
+@jax.named_scope("release")
 def _release(st: S.SimState, parents: jnp.ndarray) -> S.SimState:
     """Workflow-mode phase: refresh dependency state, cancel dead branches.
 
@@ -263,6 +269,7 @@ def _release(st: S.SimState, parents: jnp.ndarray) -> S.SimState:
     return replace(st, trace=trace)
 
 
+@jax.named_scope("arrivals")
 def _arrivals(st: S.SimState, qcap: int) -> S.SimState:
     tasks = st.tasks
     new = (tasks.status == S.NOT_ARRIVED) & (tasks.arrival <= st.time)
@@ -286,6 +293,7 @@ def _arrivals(st: S.SimState, qcap: int) -> S.SimState:
                    n_live=st.n_live - jnp.sum(overflow, dtype=jnp.int32))
 
 
+@jax.named_scope("deadline_drops")
 def _deadline_drops(st: S.SimState, tb: S.StaticTables) -> S.SimState:
     tasks, mach = st.tasks, st.machines
     n = tasks.arrival.shape[0]
@@ -406,6 +414,7 @@ def _apply_decisions_k(st: S.SimState, dec: P.Decision, use: jnp.ndarray
     return st, n_applied
 
 
+@jax.named_scope("drain")
 def _drain(st: S.SimState, tb: S.StaticTables, policy_id: jnp.ndarray,
            params: SimParams, const: tuple | None = None,
            up: jnp.ndarray | None = None,
@@ -531,6 +540,7 @@ def _drain_trace(st: S.SimState, trace, status_before) -> S.SimState:
     return replace(st, trace=trace)
 
 
+@jax.named_scope("start_tasks")
 def _start_tasks(st: S.SimState, tb: S.StaticTables,
                  up: jnp.ndarray | None = None, *,
                  pallas: bool = False) -> S.SimState:
@@ -589,6 +599,7 @@ def sorted_transitions(dyn: S.MachineDynamics) -> jnp.ndarray:
     return jnp.concatenate([trans, jnp.full((1,), jnp.inf, jnp.float32)])
 
 
+@jax.named_scope("next_event")
 def _next_event_time(st: S.SimState,
                      dyn: S.MachineDynamics | None = None,
                      parents: jnp.ndarray | None = None,

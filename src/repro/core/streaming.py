@@ -169,6 +169,7 @@ class WindowState:
 # ---------------------------------------------------------------------------
 # Window phases: retire -> refill -> compact (then the dense event phases)
 # ---------------------------------------------------------------------------
+@jax.named_scope("retire")
 def _retire(ws: WindowState) -> WindowState:
     """Fold terminal slots into the running aggregates and free them.
 
@@ -215,6 +216,7 @@ def _retire(ws: WindowState) -> WindowState:
     return dataclasses.replace(ws, retired=ws.retired | ok, agg=agg)
 
 
+@jax.named_scope("refill")
 def _refill(ws: WindowState, chunk: TaskStream,
             n_valid: jnp.ndarray) -> WindowState:
     """Load as many pending stream rows as there are free slots.
@@ -285,6 +287,7 @@ def _refill(ws: WindowState, chunk: TaskStream,
         cursor=ws.cursor + load, children_unloaded=cu, pslot=pslot))
 
 
+@jax.named_scope("compact")
 def _compact(ws: WindowState) -> WindowState:
     """Stably sort slots by global task id (never-used slots last).
 

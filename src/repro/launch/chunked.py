@@ -21,10 +21,12 @@ scenario grids ROADMAP item 3 asks for.  This module is the scale path
             Per-replica results never land on host unless
             ``keep_replicas=True``.
   overlap   an async double-buffered driver dispatches chunk N, then
-            normalizes chunk N+1 on host while the device runs, and only
-            then blocks (``jax.block_until_ready``) on chunk N-1 — at
-            most two chunks in flight, host RNG hidden behind device
-            compute.  ``core/telemetry.py`` spans record the timeline.
+            normalizes chunk N+1 on host, meant to run while the device
+            runs chunk N, and only then blocks
+            (``jax.block_until_ready``) on chunk N-1 — at most two
+            chunks in flight.  ``core/telemetry.py`` spans record the
+            timeline, on the profiler's clock too (``e2c.`` annotations),
+            so a trace shows whether the normalize actually hid.
 
 Exact summation — why the aggregate is bitwise partition-invariant
 ------------------------------------------------------------------
@@ -44,7 +46,6 @@ int32 overflow (:data:`MAX_CHUNK`).
 """
 from __future__ import annotations
 
-import contextlib
 import math
 import time
 from dataclasses import dataclass
@@ -352,30 +353,23 @@ def aggregate_metrics(metrics: dict, policy_ids,
 # ---------------------------------------------------------------------------
 @dataclass
 class ChunkedStats:
-    """Driver timing: where the wall-clock of a chunked run went.
-
-    ``overlap_s`` is host normalize time spent while the device had a
-    chunk in flight (every normalize except chunk 0's); ``overlap_frac``
-    is its share of the whole run — the double-buffering win."""
+    """Driver timing: where the wall-clock of a chunked run went, by
+    host stage.  Whether a normalize hid behind device work is a
+    question for a device trace (the ``e2c.chunk_normalize`` spans
+    beside the device ops), not for host clocks."""
     chunk: int
     n_chunks: int
     normalize_s: float = 0.0
     dispatch_s: float = 0.0
     sync_s: float = 0.0
-    overlap_s: float = 0.0
     wall_s: float = 0.0
-
-    @property
-    def overlap_frac(self) -> float:
-        return self.overlap_s / self.wall_s if self.wall_s else 0.0
 
 
 def run_chunked_experiment(spec, chunk: int, *, mesh=None,
                            policy_params=None, replicas=None,
                            keep_replicas: bool = False,
                            on_chunk: Callable[[int], None] | None = None,
-                           aspec: ME.MetricsSpec = SWEEP_SPEC,
-                           profile_dir: str | None = None):
+                           aspec: ME.MetricsSpec = SWEEP_SPEC):
     """Chunked/donated/device-reduced twin of ``run_experiment`` —
     normally reached as ``run_experiment(spec, chunk=...)``.
 
@@ -417,17 +411,18 @@ def run_chunked_experiment(spec, chunk: int, *, mesh=None,
             reps = jax.tree.map(lambda x: x[lo:hi], replicas)
         else:
             reps = X.normalize_chunk(spec, lo, hi)
-        pol_idx = jnp.asarray(_policy_index(policies, reps.policy_ids))
-        if spec.streaming:
-            args = (X.to_streams(reps, spec.stream_chunk), reps.mtype,
-                    reps.tables.eet, reps.tables.power, reps.policy_ids,
-                    reps.dynamics)
-        else:
-            args = (reps.tasks, reps.mtype, reps.tables, reps.policy_ids,
-                    reps.dynamics, reps.parents)
-        if mesh is not None:
-            from repro.launch.mesh import put_chunk
-            pol_idx, args = put_chunk((pol_idx, args), mesh, hi - lo)
+        with TL.span("stack"):
+            pol_idx = jnp.asarray(_policy_index(policies, reps.policy_ids))
+            if spec.streaming:
+                args = (X.to_streams(reps, spec.stream_chunk), reps.mtype,
+                        reps.tables.eet, reps.tables.power,
+                        reps.policy_ids, reps.dynamics)
+            else:
+                args = (reps.tasks, reps.mtype, reps.tables,
+                        reps.policy_ids, reps.dynamics, reps.parents)
+            if mesh is not None:
+                from repro.launch.mesh import put_chunk
+                pol_idx, args = put_chunk((pol_idx, args), mesh, hi - lo)
         return pol_idx, args
 
     stats = ChunkedStats(chunk=chunk, n_chunks=n_chunks)
@@ -452,49 +447,42 @@ def run_chunked_experiment(spec, chunk: int, *, mesh=None,
                  n_chunks=n_chunks, n_replicas=n_rep,
                  streaming=bool(spec.streaming),
                  policies=policies, backend=jax.default_backend()) as xsp:
+        hi = min(chunk, n_rep)
         t0 = time.perf_counter()
-        with TL.span("chunk_normalize", chunk=0, overlapped=False):
-            cur = materialize(0, min(chunk, n_rep))
+        with TL.span("chunk_normalize", chunk=0, n_replicas=hi,
+                     overlapped=False):
+            cur = materialize(0, hi)
         stats.normalize_s += time.perf_counter() - t0
         cols = {}
-        with (jax.profiler.trace(profile_dir) if profile_dir
-              else contextlib.nullcontext()):
-            for c in range(n_chunks):
-                if c == 0:
-                    keys = jax.eval_shape(
-                        X.compile_experiment(spec), *cur[1],
-                        policy_params)
-                    cols = {k: _init_column(len(policies), aspec)
-                            for k in keys}
-                while len(pending) > 1:   # retire everything but c-1
-                    retire()
-                pol_idx, args = cur
-                cur = None                # donated below — drop the refs
-                t0 = time.perf_counter()
-                with TL.span("chunk_dispatch", chunk=c):
-                    cols, m, token = step(cols, pol_idx, args,
-                                          policy_params)
-                stats.dispatch_s += time.perf_counter() - t0
-                pending.append((c, token, m))
-                if c + 1 < n_chunks:
-                    lo = (c + 1) * chunk
-                    hi = min(lo + chunk, n_rep)
-                    t0 = time.perf_counter()
-                    with TL.span("chunk_normalize", chunk=c + 1,
-                                 overlapped=True):
-                        cur = materialize(lo, hi)
-                    dt = time.perf_counter() - t0
-                    stats.normalize_s += dt
-                    stats.overlap_s += dt
-            while pending:
+        for c in range(n_chunks):
+            if c == 0:
+                keys = jax.eval_shape(
+                    X.compile_experiment(spec), *cur[1], policy_params)
+                cols = {k: _init_column(len(policies), aspec) for k in keys}
+            while len(pending) > 1:   # retire everything but c-1
                 retire()
+            pol_idx, args = cur
+            cur = None                # donated below — drop the refs
+            t0 = time.perf_counter()
+            with TL.span("chunk_dispatch", chunk=c):
+                cols, m, token = step(cols, pol_idx, args, policy_params)
+            stats.dispatch_s += time.perf_counter() - t0
+            pending.append((c, token, m))
+            if c + 1 < n_chunks:
+                lo = (c + 1) * chunk
+                hi = min(lo + chunk, n_rep)
+                t0 = time.perf_counter()
+                with TL.span("chunk_normalize", chunk=c + 1,
+                             n_replicas=hi - lo, overlapped=True):
+                    cur = materialize(lo, hi)
+                stats.normalize_s += time.perf_counter() - t0
+        while pending:
+            retire()
         agg = SweepAgg.from_device(cols, policies, aspec)
         stats.wall_s = time.perf_counter() - t_wall
         xsp.update(normalize_s=round(stats.normalize_s, 6),
                    dispatch_s=round(stats.dispatch_s, 6),
                    sync_s=round(stats.sync_s, 6),
-                   overlap_s=round(stats.overlap_s, 6),
-                   overlap_frac=round(stats.overlap_frac, 6),
                    retraces=X._CACHE_STATS["retraces"])
         TL.event("cache", **X.cache_stats())
     metrics = None

@@ -34,7 +34,6 @@ shims delegating here; their replica construction is bitwise-identical
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import os
 from dataclasses import dataclass, field
@@ -431,18 +430,24 @@ def _materialize_flat(spec: ExperimentSpec, lo: int = 0,
     of the full grid — chunked normalization is bitwise-stable."""
     hi = spec.n_replicas if hi is None else hi
     tts, mts, tabs, pids, dyns = [], [], [], [], []
-    for r in range(lo, hi):
-        tt, mt, tab, pid, dyn = _draw_flat_replica(spec, r)
-        tts.append(tt)
-        mts.append(mt)
-        tabs.append(tab)
-        pids.append(pid)
-        if dyn is not None:
-            dyns.append(dyn)
-    return Replicas(
-        _stack(tts), jnp.asarray(np.stack(mts), jnp.int32), _stack(tabs),
-        jnp.asarray(pids, jnp.int32),
-        _stack(dyns) if dyns else None, None)
+    with TL.span("draw"):
+        for r in range(lo, hi):
+            tt, mt, tab, pid, dyn = _draw_flat_replica(spec, r)
+            tts.append(tt)
+            mts.append(mt)
+            tabs.append(tab)
+            pids.append(pid)
+            if dyn is not None:
+                dyns.append(dyn)
+    with TL.span("stack"):
+        reps = Replicas(
+            _stack(tts), jnp.asarray(np.stack(mts), jnp.int32),
+            _stack(tabs), jnp.asarray(pids, jnp.int32),
+            _stack(dyns) if dyns else None, None)
+        # free the per-replica arrays inside the span, not on return
+        # (about 0.1 ms a replica on a TPU host)
+        del tts, tabs, dyns
+    return reps
 
 
 def _draw_workflow_cell(spec: ExperimentSpec, cell: int):
@@ -516,24 +521,30 @@ def _materialize_workflow(spec: ExperimentSpec, lo: int = 0,
     policies = spec.policy.policies
     n_p = len(policies)
     tts, mts, tabs, pids, dyns, pars = [], [], [], [], [], []
-    for cell in range(lo // n_p, -(-hi // n_p)):
-        tt, mt, tab, dyn, parents = _draw_workflow_cell(spec, cell)
-        for p in range(n_p):
-            r = cell * n_p + p
-            if lo <= r < hi:
-                tts.append(tt)
-                mts.append(mt)
-                tabs.append(tab)
-                pids.append(P.POLICY_IDS[policies[p]])
-                dyns.append(dyn)
-                pars.append(parents)
-    k_max = max(p.shape[1] for p in pars) if k_max is None else k_max
-    parents = np.full((hi - lo, spec.workload.n_tasks, k_max), -1, np.int32)
-    for i, p in enumerate(pars):
-        parents[i, :, :p.shape[1]] = p
-    return Replicas(
-        _stack(tts), jnp.asarray(np.stack(mts), jnp.int32), _stack(tabs),
-        jnp.asarray(pids, jnp.int32), _stack(dyns), jnp.asarray(parents))
+    with TL.span("draw"):
+        for cell in range(lo // n_p, -(-hi // n_p)):
+            tt, mt, tab, dyn, parents = _draw_workflow_cell(spec, cell)
+            for p in range(n_p):
+                r = cell * n_p + p
+                if lo <= r < hi:
+                    tts.append(tt)
+                    mts.append(mt)
+                    tabs.append(tab)
+                    pids.append(P.POLICY_IDS[policies[p]])
+                    dyns.append(dyn)
+                    pars.append(parents)
+    with TL.span("stack"):
+        k_max = max(p.shape[1] for p in pars) if k_max is None else k_max
+        parents = np.full((hi - lo, spec.workload.n_tasks, k_max), -1,
+                          np.int32)
+        for i, p in enumerate(pars):
+            parents[i, :, :p.shape[1]] = p
+        reps = Replicas(
+            _stack(tts), jnp.asarray(np.stack(mts), jnp.int32),
+            _stack(tabs), jnp.asarray(pids, jnp.int32), _stack(dyns),
+            jnp.asarray(parents))
+        del tts, tabs, dyns     # freed inside the span, as in the flat mode
+    return reps
 
 
 def normalize(spec: ExperimentSpec) -> Replicas:
@@ -588,11 +599,15 @@ def enable_compilation_cache() -> str:
     but skips the XLA compile (docs/experiments.md §Compilation cache).
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax already reads it:
-    that directory is returned and no config is touched.  Otherwise the
+    that directory is returned and no other is set.  Otherwise the
     cache goes to :data:`DEFAULT_CACHE_DIR` (``results/jax_cache`` under
     the checkout) with the size/time thresholds zeroed, so every sweep is
-    cached.  A failure to create or configure it raises.
+    cached.  Either way the cache key takes in the HLO metadata: an
+    executable of another build of the program, whose ``op_name``\ s
+    (the engine's phase scopes) differ, is never loaded in its place.
+    A failure to create or configure it raises.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env
@@ -792,7 +807,6 @@ class ExperimentResult:
 
 def run_experiment(spec: ExperimentSpec, *, mesh=None, policy_params=None,
                    replicas: Replicas | None = None,
-                   profile_dir: str | None = None,
                    chunk: int | None = None,
                    keep_replicas: bool = False,
                    on_chunk=None) -> ExperimentResult:
@@ -804,8 +818,7 @@ def run_experiment(spec: ExperimentSpec, *, mesh=None, policy_params=None,
     supplies shared learned-policy weights (``learned=True`` specs).
     ``replicas`` short-circuits normalization when the caller already
     materialized inputs (e.g. to re-run a grid under a different policy
-    column).  ``profile_dir`` wraps the execute stage in
-    ``jax.profiler.trace`` (TensorBoard-readable device profile).
+    column).
 
     ``chunk=C`` switches to the pod-scale path (``launch/chunked.py``,
     docs/scaling.md): the grid runs C replicas at a time with donated
@@ -818,24 +831,26 @@ def run_experiment(spec: ExperimentSpec, *, mesh=None, policy_params=None,
     When telemetry is enabled (``repro.core.telemetry``), each stage
     emits a span — normalize/compile/execute wall times, replica counts,
     executable-cache counters, device and mesh info — under one parent
-    ``experiment`` span (docs/observability.md).
+    ``experiment`` span (docs/observability.md).  The spans are
+    ``e2c.<stage>`` annotations too, so wrapping the call in
+    ``jax.profiler.trace`` shows them beside the device ops.
     """
     if chunk is not None:
         from repro.launch.chunked import run_chunked_experiment
         return run_chunked_experiment(
             spec, chunk, mesh=mesh, policy_params=policy_params,
             replicas=replicas, keep_replicas=keep_replicas,
-            on_chunk=on_chunk, profile_dir=profile_dir)
+            on_chunk=on_chunk)
     if keep_replicas or on_chunk is not None:
         raise ValueError("keep_replicas/on_chunk only apply with chunk=")
     with TL.span("experiment", streaming=bool(spec.streaming),
                  policies=spec.policy.policies,
                  backend=jax.default_backend(),
                  devices=jax.device_count()) as xsp:
-        with TL.span("normalize") as nsp:
-            reps = replicas if replicas is not None else normalize(spec)
-            nsp["n_replicas"] = reps.n_replicas
-            nsp["reused"] = replicas is not None
+        reused = replicas is not None
+        with TL.span("normalize", reused=reused, n_replicas=(
+                replicas.n_replicas if reused else spec.n_replicas)):
+            reps = replicas if reused else normalize(spec)
         xsp["n_replicas"] = reps.n_replicas
         with TL.span("compile") as csp:
             fn = compile_experiment(spec)
@@ -849,27 +864,23 @@ def run_experiment(spec: ExperimentSpec, *, mesh=None, policy_params=None,
                                  f"over {n_dev} devices")
             reps = jax.device_put(reps, replica_sharding(mesh))
             xsp["mesh"] = dict(getattr(mesh, "shape", {}) or {})
-        with TL.span("execute", profiled=profile_dir is not None) as esp:
-            prof = (jax.profiler.trace(profile_dir) if profile_dir
-                    else contextlib.nullcontext())
-            with prof:
-                if spec.streaming:
-                    stream = to_streams(reps, spec.stream_chunk)
-                    if mesh is not None:
-                        from repro.launch.mesh import replica_sharding
-                        stream = jax.device_put(stream,
-                                                replica_sharding(mesh))
-                    out = fn(stream, reps.mtype, reps.tables.eet,
-                             reps.tables.power, reps.policy_ids,
-                             reps.dynamics, policy_params)
-                else:
-                    out = fn(reps.tasks, reps.mtype, reps.tables,
-                             reps.policy_ids, reps.dynamics, reps.parents,
-                             policy_params)
-                # only force the sync when someone is timing the stage
-                # (keeps the default path's async dispatch untouched)
-                if profile_dir is not None or TL.current() is not None:
-                    out = jax.block_until_ready(out)
+        with TL.span("execute") as esp:
+            if spec.streaming:
+                stream = to_streams(reps, spec.stream_chunk)
+                if mesh is not None:
+                    from repro.launch.mesh import replica_sharding
+                    stream = jax.device_put(stream, replica_sharding(mesh))
+                out = fn(stream, reps.mtype, reps.tables.eet,
+                         reps.tables.power, reps.policy_ids,
+                         reps.dynamics, policy_params)
+            else:
+                out = fn(reps.tasks, reps.mtype, reps.tables,
+                         reps.policy_ids, reps.dynamics, reps.parents,
+                         policy_params)
+            # only force the sync when someone is timing the stage
+            # (keeps the default path's async dispatch untouched)
+            if TL.current() is not None:
+                out = jax.block_until_ready(out)
             esp["retraces"] = _CACHE_STATS["retraces"]
         TL.event("cache", **cache_stats())
     # the executable's output shape follows the EFFECTIVE params (the

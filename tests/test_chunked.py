@@ -316,10 +316,12 @@ def test_host_memory_stays_o_chunk():
 # ---------------------------------------------------------------------------
 # The async double-buffered driver: overlap + spans + validation
 # ---------------------------------------------------------------------------
-def test_overlap_spans_prove_normalize_hides_behind_device(tmp_path):
-    """Telemetry timeline: chunk c+1's normalize span closes BEFORE
-    chunk c's sync span — host RNG ran while the device had work in
-    flight (the double-buffering contract)."""
+def test_chunk_spans_nest_and_order(tmp_path):
+    """Telemetry timeline of the double-buffered driver: every chunk's
+    normalize, dispatch and sync span sits under the ``experiment``
+    span, and chunk c+1's normalize closes BEFORE chunk c's sync — the
+    host order that lets the normalize run while chunk c is in flight
+    (whether it actually hid is for a device trace to say)."""
     spec = flat_spec()
     log = TL.enable(str(tmp_path))
     try:
@@ -329,18 +331,48 @@ def test_overlap_spans_prove_normalize_hides_behind_device(tmp_path):
     recs = [r for r in TL.read_jsonl(log.path) if r["kind"] == "span"]
     order = {(r["name"], r.get("chunk")): i for i, r in enumerate(recs)}
     n_chunks = res.chunked.n_chunks
-    overlapped = [r for r in recs if r["name"] == "chunk_normalize"
-                  and r.get("overlapped")]
-    assert len(overlapped) == n_chunks - 1
+    parent = next(r for r in recs if r["name"] == "experiment")
+    assert parent["chunked"] is True and parent["n_chunks"] == n_chunks
+    for name in ("chunk_normalize", "chunk_dispatch", "chunk_sync"):
+        mine = [r for r in recs if r["name"] == name]
+        assert [r["chunk"] for r in sorted(mine, key=lambda r: r["chunk"])
+                ] == list(range(n_chunks)), name
+        assert all(r["parent"] == parent["span"] for r in mine), name
+    normalized = [r for r in recs if r["name"] == "chunk_normalize"]
+    assert [r["overlapped"] for r in normalized] == \
+        [False] + [True] * (n_chunks - 1)
+    assert sum(r["n_replicas"] for r in normalized) == spec.n_replicas
     for c in range(n_chunks - 1):
         assert order[("chunk_normalize", c + 1)] < \
             order[("chunk_sync", c)], f"chunk {c}"
-    parent = next(r for r in recs if r["name"] == "experiment")
-    assert parent["chunked"] is True
-    assert parent["overlap_s"] > 0
-    assert res.chunked.overlap_s > 0
-    assert res.chunked.overlap_frac > 0
-    assert res.chunked.normalize_s >= res.chunked.overlap_s
+        assert order[("chunk_dispatch", c)] < \
+            order[("chunk_normalize", c + 1)], f"chunk {c}"
+    assert 0 < res.chunked.normalize_s < res.chunked.wall_s
+
+
+@pytest.mark.parametrize("kind", ["flat", "workflow", "streaming"])
+@pytest.mark.parametrize("chunk", [None, 12])
+def test_draw_and_stack_are_children_of_every_normalize(tmp_path, kind,
+                                                        chunk):
+    """Each normalize (monolithic) or chunk normalize span holds one
+    ``draw`` (the per-replica host loop) and a ``stack`` (building the
+    stacked inputs) — once per normalize, never once per replica."""
+    spec = SPECS[kind]().with_(n_replicas=24)
+    log = TL.enable(str(tmp_path))
+    try:
+        X.run_experiment(spec, chunk=chunk)
+    finally:
+        TL.disable()
+    recs = [r for r in TL.read_jsonl(log.path) if r["kind"] == "span"]
+    outer = [r for r in recs if r["name"] in ("normalize",
+                                              "chunk_normalize")]
+    assert len(outer) == (1 if chunk is None else 2)
+    for o in outer:
+        kids = [r["name"] for r in recs if r["parent"] == o["span"]]
+        assert kids.count("draw") == 1 and "stack" in kids, kids
+        assert set(kids) == {"draw", "stack"}, kids
+    n_draws = sum(r["name"] == "draw" for r in recs)
+    assert n_draws == len(outer)
 
 
 def test_chunked_runs_through_shared_executable(shared_sweep):

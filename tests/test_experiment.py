@@ -241,15 +241,19 @@ def cache_config():
     """Snapshot the cache knobs and restore them, so enabling the cache
     here does not make every later compile in this worker write to it."""
     saved = {k: getattr(jax.config, k) for k in _CACHE_KNOBS}
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     yield saved
     for k, v in saved.items():
         jax.config.update(k, v)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
 
 
 def test_compilation_cache_honours_env(cache_config, monkeypatch, tmp_path):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     assert X.enable_compilation_cache() == str(tmp_path)
     assert {k: getattr(jax.config, k) for k in _CACHE_KNOBS} == cache_config
+    # another build's executable, with other op_names, is never loaded
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_compilation_cache_default_is_fixed_in_checkout(

@@ -20,6 +20,7 @@ from __future__ import annotations
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from _hyp import given, settings, st  # hypothesis optional (dev extra)
@@ -328,6 +329,104 @@ def test_experiment_emits_spans_and_tail_columns(tmp_path):
     assert spans["compile"]["misses"] >= 1
     events = [r for r in recs if r["kind"] == "event" and r["name"] == "cache"]
     assert events and "retraces" in events[-1]
+
+
+def test_span_counts_trace_and_lower_seconds(tmp_path):
+    """A jitted function first called inside a span: the span carries
+    the trace, lower and backend seconds it caused; the log opens with
+    the process's seconds before it, and ``disable`` writes the log's
+    own totals, each as one ``compile_clock`` event."""
+    jax.jit(lambda x: jnp.cos(x) - 2)(jnp.arange(5.0))
+    log = TL.enable(str(tmp_path))
+    try:
+        with TL.span("first_call"):
+            jax.jit(lambda x: jnp.sin(x) * 3 + 1)(jnp.arange(7.0))
+        with TL.span("nothing_compiled"):
+            pass
+    finally:
+        TL.disable()
+    recs = TL.read_jsonl(log.path)
+    spans = {r["name"]: r for r in recs if r["kind"] == "span"}
+    first = spans["first_call"]
+    assert first["trace_s"] > 0 and first["lower_s"] > 0
+    assert first["trace_s"] + first["lower_s"] <= first["dur_s"]
+    assert not {"trace_s", "lower_s"} & set(spans["nothing_compiled"])
+    opened, clock = recs[0], recs[-1]
+    assert opened["name"] == "compile_clock" and opened["window"] == "before"
+    assert opened["trace_s"] > 0 and opened["lower_s"] > 0
+    assert clock["kind"] == "event" and clock["name"] == "compile_clock"
+    assert clock["window"] == "log"
+    assert clock["trace_s"] >= first["trace_s"] > 0
+    assert clock["lower_s"] >= first["lower_s"] > 0
+    assert set(clock) >= {"trace_s", "lower_s", "backend_s"}
+
+
+def test_nested_traces_count_once():
+    """An event that encloses earlier ones (an outer trace around an
+    inner jit's) adds only the time they leave uncovered."""
+    clock = TL.CompileClock()
+    clock.on_event("trace_s", 0.0)
+    inner = clock.totals["trace_s"]
+    clock.on_event("trace_s", 10.0)           # began long before: encloses
+    assert clock.totals["trace_s"] == pytest.approx(10.0, abs=1e-3)
+    assert inner <= 1e-3
+
+
+def test_span_is_a_profiler_annotation(tmp_path):
+    """With no log on, ``span()`` still lands in a ``jax.profiler``
+    trace as ``e2c.<name>`` with its attributes as stats."""
+    import glob
+
+    from jax.profiler import ProfileData
+    TL.disable()
+    with jax.profiler.trace(str(tmp_path)):
+        with TL.span("probe", n_replicas=3, chunk=1):
+            jax.block_until_ready(jnp.ones(4) + 1)
+    path, = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    found = [dict(ev.stats)
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name == "e2c.probe"]
+    assert found == [{"n_replicas": 3, "chunk": 1}]
+
+
+PHASE_SCOPES = {
+    "dense": ("next_event", "completions", "availability", "release",
+              "arrivals", "deadline_drops", "drain", "start_tasks"),
+    "stream": ("next_event", "completions", "availability", "arrivals",
+               "deadline_drops", "drain", "start_tasks", "retire", "refill",
+               "compact"),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(PHASE_SCOPES))
+def test_compiled_sweeps_carry_phase_scopes(kind):
+    """The dense sweep (scenario + workflow, so every phase runs) and
+    the stream sweep name each engine phase in their HLO ``op_name``."""
+    import re
+
+    from repro.launch import experiment as X
+    scen = X.ScenarioAxis((0.1,), ("powersave",), spot_frac=0.5)
+    if kind == "dense":
+        wl = X.WorkloadAxis(8, 2, shapes=("fork_join",))
+    else:
+        wl = X.WorkloadAxis(8, 2, streaming=4)
+    spec = X.ExperimentSpec(2, X.FleetAxis(3, 2), wl, scenario=scen,
+                            policy=X.PolicyAxis(("mct",)), seed=0)
+    reps = X.normalize(spec)
+    fn = X.compile_experiment(spec)
+    if kind == "dense":
+        args = (reps.tasks, reps.mtype, reps.tables, reps.policy_ids,
+                reps.dynamics, reps.parents, None)
+    else:
+        args = (X.to_streams(reps, spec.stream_chunk), reps.mtype,
+                reps.tables.eet, reps.tables.power, reps.policy_ids,
+                reps.dynamics, None)
+    hlo = fn.lower(*args).compile().as_text()
+    parts = {p for name in re.findall(r'op_name="([^"]*)"', hlo)
+             for p in name.split("/")}
+    missing = [ph for ph in PHASE_SCOPES[kind] if ph not in parts]
+    assert not missing, missing
 
 
 def test_cache_stats_count_retraces():
