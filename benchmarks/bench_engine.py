@@ -76,7 +76,7 @@ def time_scenario_sweep(n_replicas: int) -> tuple[float, float]:
         n_replicas, XP.FleetAxis(N_MACHINES), XP.WorkloadAxis(N_TASKS),
         scenario=SCEN_AXIS,
         policy=XP.PolicyAxis(("mct", "minmin", "ee_mct")), seed=0)
-    reps = XP.normalize(spec)
+    reps = jax.device_put(XP.normalize(spec))
     sweep = XP.compile_experiment(spec)
     dt = _time_fn(sweep, reps.legacy() + (None, None))
     return dt, dt / n_replicas
@@ -173,7 +173,7 @@ def time_workflow_sweep(n_replicas: int) -> tuple[float, float, float]:
         n_replicas, XP.FleetAxis(N_MACHINES),
         XP.WorkloadAxis(N_TASKS, shapes=("chain",)),
         policy=XP.PolicyAxis(("mct",)), seed=0)
-    wf = XP.normalize(wf_spec)
+    wf = jax.device_put(XP.normalize(wf_spec))
     sweep = XP.compile_sweep()
     base = make_replicas(n_replicas, N_TASKS, N_MACHINES,
                          policies=["mct"], seed=0)

@@ -756,21 +756,31 @@ def run_sim(tasks: S.TaskTable, mtype: jnp.ndarray, tables: S.StaticTables,
     return st
 
 
-def make_tables(eet: EETTable | np.ndarray, power: np.ndarray,
-                n_tasks: int, *, noise: np.ndarray | None = None,
-                rank: np.ndarray | None = None) -> S.StaticTables:
-    """``rank`` (optional (N,) f32): HEFT upward ranks for workflow
-    workloads (``workload.upward_ranks``); zeros otherwise, where the
-    ``heft`` policy degenerates to head-of-queue MCT."""
+def make_host_tables(eet: EETTable | np.ndarray, power: np.ndarray,
+                     n_tasks: int, *, noise: np.ndarray | None = None,
+                     rank: np.ndarray | None = None) -> S.StaticTables:
+    """The static tables with float32 numpy leaves, built on the host
+    with no device work.  ``rank`` (optional (N,) f32): HEFT upward
+    ranks for workflow workloads (``workload.upward_ranks``); zeros
+    otherwise, where the ``heft`` policy degenerates to head-of-queue
+    MCT."""
     eet_arr = eet.eet if isinstance(eet, EETTable) else np.asarray(eet)
     if noise is None:
         noise = np.ones((n_tasks,), np.float32)
     if rank is None:
         rank = np.zeros((n_tasks,), np.float32)
-    return S.StaticTables(eet=jnp.asarray(eet_arr, jnp.float32),
-                          power=jnp.asarray(power, jnp.float32),
-                          noise=jnp.asarray(noise, jnp.float32),
-                          rank=jnp.asarray(rank, jnp.float32))
+    return S.StaticTables(eet=np.asarray(eet_arr, np.float32),
+                          power=np.asarray(power, np.float32),
+                          noise=np.asarray(noise, np.float32),
+                          rank=np.asarray(rank, np.float32))
+
+
+def make_tables(eet: EETTable | np.ndarray, power: np.ndarray,
+                n_tasks: int, *, noise: np.ndarray | None = None,
+                rank: np.ndarray | None = None) -> S.StaticTables:
+    """:func:`make_host_tables` on the device."""
+    return jax.device_put(make_host_tables(eet, power, n_tasks,
+                                           noise=noise, rank=rank))
 
 
 def simulate(workload, eet: EETTable, power: np.ndarray,

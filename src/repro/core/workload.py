@@ -50,19 +50,25 @@ class Workload:
     def n_tasks(self) -> int:
         return self.arrival.shape[0]
 
-    def to_task_table(self) -> TaskTable:
-        import jax.numpy as jnp
+    def host_task_table(self) -> TaskTable:
+        """The engine's initial task table with numpy leaves: builds it
+        on the host and starts no device work."""
         n = self.n_tasks
         return TaskTable(
-            arrival=jnp.asarray(self.arrival),
-            type_id=jnp.asarray(self.type_id),
-            deadline=jnp.asarray(self.deadline),
-            status=jnp.zeros((n,), jnp.int32),
-            machine=jnp.full((n,), -1, jnp.int32),
-            seq=jnp.zeros((n,), jnp.int32),
-            t_start=jnp.zeros((n,), jnp.float32),
-            t_end=jnp.zeros((n,), jnp.float32),
+            arrival=self.arrival,
+            type_id=self.type_id,
+            deadline=self.deadline,
+            status=np.zeros((n,), np.int32),
+            machine=np.full((n,), -1, np.int32),
+            seq=np.zeros((n,), np.int32),
+            t_start=np.zeros((n,), np.float32),
+            t_end=np.zeros((n,), np.float32),
         )
+
+    def to_task_table(self) -> TaskTable:
+        """:meth:`host_task_table` on the device."""
+        import jax
+        return jax.device_put(self.host_task_table())
 
 
 def poisson_workload(n_tasks: int, rate: float, n_task_types: int, *,
@@ -569,16 +575,22 @@ class Scenario:
     def n_machines(self) -> int:
         return self.speed.shape[0]
 
-    def dynamics(self):
-        import jax.numpy as jnp
+    def host_dynamics(self):
+        """The engine's ``MachineDynamics`` with numpy leaves: builds it
+        on the host and starts no device work."""
         from repro.core.state import MachineDynamics
         return MachineDynamics(
-            speed=jnp.asarray(self.speed),
-            power_scale=jnp.asarray(self.power_scale),
-            down_start=jnp.asarray(self.down_start),
-            down_end=jnp.asarray(self.down_end),
-            kill=jnp.asarray(self.kill),
+            speed=self.speed,
+            power_scale=self.power_scale,
+            down_start=self.down_start,
+            down_end=self.down_end,
+            kill=self.kill,
         )
+
+    def dynamics(self):
+        """:meth:`host_dynamics` on the device."""
+        import jax
+        return jax.device_put(self.host_dynamics())
 
 
 def make_scenario(workload: Workload, n_machines: int, *,

@@ -21,8 +21,9 @@ scenario grids ROADMAP item 3 asks for.  This module is the scale path
             Per-replica results never land on host unless
             ``keep_replicas=True``.
   overlap   an async double-buffered driver dispatches chunk N, then
-            normalizes chunk N+1 on host, meant to run while the device
-            runs chunk N, and only then blocks
+            normalizes chunk N+1 on host while the device runs chunk N
+            (the normalize starts no device work until the chunk's one
+            transfer per input leaf), and only then blocks
             (``jax.block_until_ready``) on chunk N-1 — at most two
             chunks in flight.  ``core/telemetry.py`` spans record the
             timeline, on the profiler's clock too (``e2c.`` annotations),
@@ -407,23 +408,20 @@ def run_chunked_experiment(spec, chunk: int, *, mesh=None,
                              f"over {n_dev} devices")
 
     def materialize(lo: int, hi: int):
+        """Chunk ``[lo, hi)``'s step inputs, built on the host and moved
+        to the device in one ``device_put``: its only device work."""
         if replicas is not None:
             reps = jax.tree.map(lambda x: x[lo:hi], replicas)
         else:
             reps = X.normalize_chunk(spec, lo, hi)
-        with TL.span("stack"):
-            pol_idx = jnp.asarray(_policy_index(policies, reps.policy_ids))
-            if spec.streaming:
-                args = (X.to_streams(reps, spec.stream_chunk), reps.mtype,
-                        reps.tables.eet, reps.tables.power,
-                        reps.policy_ids, reps.dynamics)
-            else:
-                args = (reps.tasks, reps.mtype, reps.tables,
-                        reps.policy_ids, reps.dynamics, reps.parents)
+        with TL.span("stack") as sp:
+            inputs = (_policy_index(policies, reps.policy_ids),
+                      X.sweep_args(spec, reps))
+            sp.update(X.h2d(inputs))
             if mesh is not None:
                 from repro.launch.mesh import put_chunk
-                pol_idx, args = put_chunk((pol_idx, args), mesh, hi - lo)
-        return pol_idx, args
+                return put_chunk(inputs, mesh, hi - lo)
+            return jax.device_put(inputs)
 
     stats = ChunkedStats(chunk=chunk, n_chunks=n_chunks)
     step = _compile_chunk_step(params, aspec, spec.streaming,

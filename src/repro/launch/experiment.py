@@ -18,6 +18,8 @@ boolean flags.  This module collapses them into one pipeline
               stacked :class:`Replicas` pytree (the padding / pairing /
               dynamics-trace logic previously duplicated across the
               ``make_*_replicas`` builders).
+              Its leaves are numpy: it starts no device work, and the
+              caller moves the inputs in one transfer per leaf.
   compile    :func:`compile_sweep` — ONE canonical jitted executable per
               ``SimParams``, cached process-wide, so same-shape re-runs
               never retrace (bench check T8).  Optional inputs
@@ -58,7 +60,7 @@ __all__ = [
     "ExperimentSpec", "Replicas", "ExperimentResult", "normalize",
     "normalize_chunk",
     "compile_sweep", "compile_stream_sweep", "compile_experiment",
-    "run_experiment", "to_streams",
+    "run_experiment", "to_streams", "sweep_args", "h2d",
     "summarize_replica", "cache_stats", "clear_cache",
 ]
 
@@ -326,7 +328,9 @@ class ExperimentSpec:
 # normalize: spec -> stacked replicas
 # ---------------------------------------------------------------------------
 class Replicas(NamedTuple):
-    """Stacked per-replica inputs (leading axis R on every leaf).
+    """Stacked per-replica inputs (leading axis R on every leaf): numpy
+    leaves as :func:`normalize` builds them, device leaves once placed
+    (``jax.device_put``).
 
     ``dynamics`` / ``parents`` are None when the spec compiles them out;
     ``legacy()`` returns the positional tuple shape the pre-spec
@@ -352,8 +356,7 @@ class Replicas(NamedTuple):
 
 
 def _stack(trees):
-    return jax.tree.map(
-        lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *trees)
+    return jax.tree.map(lambda *xs: np.stack(xs), *trees)
 
 
 def _draw_power(rng, n_machine_types: int) -> np.ndarray:
@@ -411,13 +414,13 @@ def _draw_flat_replica(spec: ExperimentSpec, r: int):
             spot=(rng.random() < sc.spot_frac),
             dvfs=sc.dvfs_states[(r // n_f) % n_d],
             n_intervals=sc.n_intervals, seed=spec.seed + 31 * r)
-        dyn = scen.dynamics()
+        dyn = scen.host_dynamics()
         pol = policies[(r // (n_f * n_d)) % n_p]
     else:
         pol = policies[r % n_p]
     noise = rng.lognormal(0.0, 0.1, wk.n_tasks).astype(np.float32)
-    tt = wl.to_task_table()
-    tab = E.make_tables(eet, power, wk.n_tasks, noise=noise)
+    tt = wl.host_task_table()
+    tab = E.make_host_tables(eet, power, wk.n_tasks, noise=noise)
     mt = rng.integers(0, fl.n_machine_types, fl.n_machines)
     return tt, mt, tab, P.POLICY_IDS[pol], dyn
 
@@ -441,12 +444,9 @@ def _materialize_flat(spec: ExperimentSpec, lo: int = 0,
                 dyns.append(dyn)
     with TL.span("stack"):
         reps = Replicas(
-            _stack(tts), jnp.asarray(np.stack(mts), jnp.int32),
-            _stack(tabs), jnp.asarray(pids, jnp.int32),
+            _stack(tts), np.stack(mts).astype(np.int32),
+            _stack(tabs), np.asarray(pids, np.int32),
             _stack(dyns) if dyns else None, None)
-        # free the per-replica arrays inside the span, not on return
-        # (about 0.1 ms a replica on a TPU host)
-        del tts, tabs, dyns
     return reps
 
 
@@ -473,11 +473,11 @@ def _draw_workflow_cell(spec: ExperimentSpec, cell: int):
                             % len(sc.dvfs_states)],
         n_intervals=sc.n_intervals, seed=spec.seed + 31 * cell)
     noise = crng.lognormal(0.0, 0.1, wk.n_tasks).astype(np.float32)
-    tt = wf.workload.to_task_table()
+    tt = wf.workload.host_task_table()
     mt = crng.integers(0, fl.n_machine_types, fl.n_machines)
-    tab = E.make_tables(eet, power, wk.n_tasks, noise=noise,
-                        rank=wf.ranks(eet.eet.mean(1)))
-    return tt, mt, tab, scen.dynamics(), wf.parents
+    tab = E.make_host_tables(eet, power, wk.n_tasks, noise=noise,
+                             rank=wf.ranks(eet.eet.mean(1)))
+    return tt, mt, tab, scen.host_dynamics(), wf.parents
 
 
 _KMAX_CACHE: dict[ExperimentSpec, int] = {}
@@ -540,17 +540,21 @@ def _materialize_workflow(spec: ExperimentSpec, lo: int = 0,
         for i, p in enumerate(pars):
             parents[i, :, :p.shape[1]] = p
         reps = Replicas(
-            _stack(tts), jnp.asarray(np.stack(mts), jnp.int32),
-            _stack(tabs), jnp.asarray(pids, jnp.int32), _stack(dyns),
-            jnp.asarray(parents))
-        del tts, tabs, dyns     # freed inside the span, as in the flat mode
+            _stack(tts), np.stack(mts).astype(np.int32),
+            _stack(tabs), np.asarray(pids, np.int32), _stack(dyns),
+            parents)
     return reps
 
 
 def normalize(spec: ExperimentSpec) -> Replicas:
     """Materialize the spec's grid into one stacked :class:`Replicas`
     pytree — the normalization pass of the pipeline (padding parent
-    tables, pairing policy grids, materializing dynamics traces)."""
+    tables, pairing policy grids, materializing dynamics traces).
+
+    Every leaf is a numpy array built on the host: normalize starts no
+    device work, so it can run beside a computation on the device, and
+    the caller places the result once (``jax.device_put``, one transfer
+    per leaf, straight to the shards under a sharding)."""
     if spec.workflow:
         return _materialize_workflow(spec)
     return _materialize_flat(spec)
@@ -561,6 +565,7 @@ def normalize_chunk(spec: ExperimentSpec, lo: int, hi: int) -> Replicas:
     to slicing :func:`normalize`'s output, without drawing the other
     replicas (per-replica/per-cell RNG substreams make the grid
     random-access; launch/chunked.py normalizes one chunk at a time).
+    Host leaves, as :func:`normalize`.
     """
     if not (0 <= lo < hi <= spec.n_replicas):
         raise ValueError(f"chunk [{lo}, {hi}) outside grid "
@@ -711,9 +716,9 @@ def compile_stream_sweep(params):
 
 def to_streams(reps: Replicas, chunk: int):
     """Repack stacked ``(R, N)`` replica columns as ``(R, nc, C)``
-    :class:`streaming.TaskStream` columns (the batch analogue of
-    ``streaming.make_stream``; per-task noise rides in the stream, the
-    tail chunk pads with inert ``gid = -1`` rows)."""
+    :class:`streaming.TaskStream` columns of numpy arrays (the batch
+    analogue of ``streaming.make_stream``; per-task noise rides in the
+    stream, the tail chunk pads with inert ``gid = -1`` rows)."""
     from repro.core import streaming as ST
     if reps.parents is not None:
         raise ValueError("streaming replicas cannot carry parent tables")
@@ -727,12 +732,11 @@ def to_streams(reps: Replicas, chunk: int):
         x = np.asarray(x)
         out = np.full((r, total), fill, x.dtype)
         out[:, :n] = x
-        return jnp.asarray(out.reshape(r, n_chunks, chunk))
+        return out.reshape(r, n_chunks, chunk)
 
     gid = np.full((total,), -1, np.int32)
     gid[:n] = np.arange(n, dtype=np.int32)
-    gid = jnp.asarray(np.broadcast_to(gid.reshape(1, n_chunks, chunk),
-                                      (r, n_chunks, chunk)))
+    gid = np.tile(gid.reshape(1, n_chunks, chunk), (r, 1, 1))
     return ST.TaskStream(
         arrival=pad(reps.tasks.arrival, np.inf),
         type_id=pad(reps.tasks.type_id, 0),
@@ -741,6 +745,28 @@ def to_streams(reps: Replicas, chunk: int):
         rank=pad(reps.tables.rank, 0.0),
         gid=gid,
     )
+
+
+def sweep_args(spec: ExperimentSpec, reps: Replicas) -> tuple:
+    """The spec's executable inputs from ``reps``, all but
+    ``policy_params``: the dense sweep takes the replicas' columns as
+    they are, the streaming sweep the task columns as
+    :func:`to_streams` repacks them."""
+    if spec.streaming:
+        return (to_streams(reps, spec.stream_chunk), reps.mtype,
+                reps.tables.eet, reps.tables.power, reps.policy_ids,
+                reps.dynamics)
+    return (reps.tasks, reps.mtype, reps.tables, reps.policy_ids,
+            reps.dynamics, reps.parents)
+
+
+def h2d(tree) -> dict:
+    """What one ``jax.device_put`` of ``tree`` moves from the host: one
+    transfer per leaf not yet on a device (``transfers``), and their
+    bytes (``h2d_bytes``) — the ``stack`` span's attributes."""
+    host = [x for x in jax.tree.leaves(tree) if not isinstance(x, jax.Array)]
+    return {"transfers": len(host),
+            "h2d_bytes": sum(np.asarray(x).nbytes for x in host)}
 
 
 def compile_experiment(spec: ExperimentSpec):
@@ -776,7 +802,9 @@ class ExperimentResult:
     ``launch/chunked.py::SweepAgg`` in ``agg`` (plus driver timing in
     ``chunked``); ``replicas``/``metrics`` are then ``None`` unless
     ``keep_replicas=True`` stacked host copies of the per-replica
-    metrics back together."""
+    metrics back together.  Otherwise ``replicas`` holds the inputs as
+    :func:`normalize` built them (host leaves) or as the caller passed
+    them."""
     spec: ExperimentSpec
     replicas: Replicas | None
     metrics: dict | None
@@ -843,40 +871,35 @@ def run_experiment(spec: ExperimentSpec, *, mesh=None, policy_params=None,
             on_chunk=on_chunk)
     if keep_replicas or on_chunk is not None:
         raise ValueError("keep_replicas/on_chunk only apply with chunk=")
+    reused = replicas is not None
+    n_rep = replicas.n_replicas if reused else spec.n_replicas
+    sharding = None
+    if mesh is not None:
+        from repro.launch.mesh import mesh_device_count, replica_sharding
+        n_dev = mesh_device_count(mesh)
+        if n_rep % n_dev:
+            raise ValueError(f"n_replicas {n_rep} must divide "
+                             f"over {n_dev} devices")
+        sharding = replica_sharding(mesh)
     with TL.span("experiment", streaming=bool(spec.streaming),
                  policies=spec.policy.policies,
                  backend=jax.default_backend(),
                  devices=jax.device_count()) as xsp:
-        reused = replicas is not None
-        with TL.span("normalize", reused=reused, n_replicas=(
-                replicas.n_replicas if reused else spec.n_replicas)):
+        with TL.span("normalize", reused=reused, n_replicas=n_rep):
             reps = replicas if reused else normalize(spec)
-        xsp["n_replicas"] = reps.n_replicas
+            with TL.span("stack") as ssp:
+                args = sweep_args(spec, reps)
+                ssp.update(h2d(args))
+                args = jax.device_put(args, sharding)
+        xsp["n_replicas"] = n_rep
+        if mesh is not None:
+            xsp["mesh"] = dict(getattr(mesh, "shape", {}) or {})
         with TL.span("compile") as csp:
             fn = compile_experiment(spec)
             csp.update(cache_stats())
             csp["persistent_cache_dir"] = persistent_cache_dir()
-        if mesh is not None:
-            from repro.launch.mesh import mesh_device_count, replica_sharding
-            n_dev = mesh_device_count(mesh)
-            if reps.n_replicas % n_dev:
-                raise ValueError(f"n_replicas {reps.n_replicas} must divide "
-                                 f"over {n_dev} devices")
-            reps = jax.device_put(reps, replica_sharding(mesh))
-            xsp["mesh"] = dict(getattr(mesh, "shape", {}) or {})
         with TL.span("execute") as esp:
-            if spec.streaming:
-                stream = to_streams(reps, spec.stream_chunk)
-                if mesh is not None:
-                    from repro.launch.mesh import replica_sharding
-                    stream = jax.device_put(stream, replica_sharding(mesh))
-                out = fn(stream, reps.mtype, reps.tables.eet,
-                         reps.tables.power, reps.policy_ids,
-                         reps.dynamics, policy_params)
-            else:
-                out = fn(reps.tasks, reps.mtype, reps.tables,
-                         reps.policy_ids, reps.dynamics, reps.parents,
-                         policy_params)
+            out = fn(*args, policy_params)
             # only force the sync when someone is timing the stage
             # (keeps the default path's async dispatch untouched)
             if TL.current() is not None:

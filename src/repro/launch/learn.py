@@ -26,6 +26,7 @@ import json
 import os
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -69,8 +70,8 @@ def make_grid(n_replicas: int, n_tasks: int, n_machines: int,
     """DEPRECATED shim -> ``normalize(grid_spec(...)).legacy()``."""
     from repro.launch.sim import _deprecated
     _deprecated("make_grid", "normalize(learn.grid_spec(...))")
-    return normalize(grid_spec(n_replicas, n_tasks, n_machines,
-                               **kw)).legacy()
+    return jax.device_put(normalize(grid_spec(n_replicas, n_tasks,
+                                              n_machines, **kw))).legacy()
 
 
 def scoreboard(inputs: tuple, policies: list[str],
@@ -138,13 +139,13 @@ def train_and_evaluate(*, n_train: int = 16, n_test: int = 16,
     saw) — the generalization axis the paper's scenario studies sweep.
     """
     t0 = time.perf_counter()
-    train_grid = normalize(grid_spec(
+    train_grid = jax.device_put(normalize(grid_spec(
         n_train, n_tasks, n_machines, arrivals=("poisson", "bursty"),
-        seed=seed)).legacy()
-    test_grid = normalize(grid_spec(
+        seed=seed))).legacy()
+    test_grid = jax.device_put(normalize(grid_spec(
         n_test, n_tasks, n_machines,
         arrivals=("poisson", "diurnal", "onoff"),
-        seed=seed + 10_000)).legacy()
+        seed=seed + 10_000))).legacy()
     trained, train_hist = {}, {}
     for pol in policies:
         res = TP.train(train_grid, policy=pol, sim_params=sim_params,

@@ -260,7 +260,7 @@ def make_replicas(n_replicas: int, n_tasks: int, n_machines: int,
         n_replicas, FleetAxis(n_machines, n_machine_types),
         WorkloadAxis(n_tasks, n_task_types, rate),
         policy=PolicyAxis(tuple(policies)), seed=seed)
-    return normalize(spec).legacy()
+    return jax.device_put(normalize(spec)).legacy()
 
 
 def make_scenario_replicas(n_replicas: int, n_tasks: int, n_machines: int,
@@ -291,7 +291,7 @@ def make_scenario_replicas(n_replicas: int, n_tasks: int, n_machines: int,
         scenario=ScenarioAxis(tuple(fail_rates), tuple(dvfs_states),
                               spot_frac, mttr, n_intervals),
         policy=PolicyAxis(tuple(policies)), seed=seed)
-    return normalize(spec).legacy()
+    return jax.device_put(normalize(spec)).legacy()
 
 
 def make_workflow_replicas(n_replicas: int, n_tasks: int, n_machines: int,
@@ -321,7 +321,7 @@ def make_workflow_replicas(n_replicas: int, n_tasks: int, n_machines: int,
         scenario=ScenarioAxis(tuple(fail_rates), tuple(dvfs_states),
                               spot_frac, mttr, n_intervals),
         policy=PolicyAxis(tuple(policies)), seed=seed)
-    return normalize(spec).legacy()
+    return jax.device_put(normalize(spec)).legacy()
 
 
 # ---------------------------------------------------------------------------
@@ -412,6 +412,6 @@ def build_sharded_sweep(mesh, n_replicas: int, n_tasks: int,
             policy=PolicyAxis(("mct", "minmin", "ee_mct") if scenarios
                               else ("fcfs", "met", "mct", "minmin",
                                     "ee_mct")))
-        inputs = normalize(spec).legacy()
+        inputs = jax.device_put(normalize(spec)).legacy()
     return SimSweepArtifacts(jitted=jitted, inputs=inputs,
                              n_replicas=n_replicas)

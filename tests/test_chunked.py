@@ -265,6 +265,145 @@ def test_normalize_chunk_range_validation():
 
 
 # ---------------------------------------------------------------------------
+# Host-built inputs: no device work until one transfer per leaf
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kind", ["flat", "scenario", "workflow",
+                                  "streaming"])
+def test_normalize_starts_no_device_work(kind):
+    """The draws, the stacking and the stream repack run on the host:
+    under a guard that refuses every transfer (on the CPU plain
+    ``disallow`` lets ``jnp.asarray`` through), each returns numpy
+    leaves only."""
+    spec = SPECS[kind]()
+    with jax.transfer_guard("disallow_explicit"):
+        if kind == "workflow":
+            outs = [X._draw_workflow_cell(spec, 3)]
+        else:
+            outs = [X._draw_flat_replica(spec, 5)]
+        reps = X.normalize_chunk(spec, 2, 11)
+        outs += [X.normalize(spec), reps, X.sweep_args(spec, reps)]
+    for out in outs:
+        leaves = jax.tree.leaves(out)
+        assert leaves and all(isinstance(x, (np.ndarray, int))
+                              for x in leaves), {type(x) for x in leaves}
+
+
+def _device_constructors(monkeypatch):
+    """Swap the host constructors for the device constructors the normalize
+    used to call (``jnp.asarray``/``jnp.zeros``/``jnp.full`` per leaf,
+    ``jnp.stack`` over device arrays) — the reference path."""
+    from repro.core import state as S
+    from repro.core.workload import Scenario, Workload
+
+    def task_table(w):
+        n = w.n_tasks
+        return S.TaskTable(
+            arrival=jnp.asarray(w.arrival), type_id=jnp.asarray(w.type_id),
+            deadline=jnp.asarray(w.deadline),
+            status=jnp.zeros((n,), jnp.int32),
+            machine=jnp.full((n,), -1, jnp.int32),
+            seq=jnp.zeros((n,), jnp.int32),
+            t_start=jnp.zeros((n,), jnp.float32),
+            t_end=jnp.zeros((n,), jnp.float32))
+
+    def dynamics(sc):
+        return S.MachineDynamics(
+            speed=jnp.asarray(sc.speed),
+            power_scale=jnp.asarray(sc.power_scale),
+            down_start=jnp.asarray(sc.down_start),
+            down_end=jnp.asarray(sc.down_end), kill=jnp.asarray(sc.kill))
+
+    def tables(eet, power, n_tasks, *, noise=None, rank=None):
+        noise = np.ones(n_tasks, np.float32) if noise is None else noise
+        rank = np.zeros(n_tasks, np.float32) if rank is None else rank
+        return S.StaticTables(
+            eet=jnp.asarray(eet.eet, jnp.float32),
+            power=jnp.asarray(power, jnp.float32),
+            noise=jnp.asarray(noise, jnp.float32),
+            rank=jnp.asarray(rank, jnp.float32))
+
+    monkeypatch.setattr(Workload, "host_task_table", task_table)
+    monkeypatch.setattr(Scenario, "host_dynamics", dynamics)
+    monkeypatch.setattr(E, "make_host_tables", tables)
+    monkeypatch.setattr(X, "_stack", lambda trees: jax.tree.map(
+        lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *trees))
+
+
+def _executable_inputs(spec, chunk, mesh, monkeypatch) -> list:
+    """What each executable call of ``run_experiment`` receives (one
+    entry a call: the inputs, and the policy index of a chunk step);
+    the executables are stubbed, so nothing is computed."""
+    seen = []
+    if chunk is None:
+        monkeypatch.setattr(X, "compile_experiment", lambda spec: (
+            lambda *args: seen.append(args[:-1]) or {}))
+    else:
+        def fake_step(params, aspec, streaming, keep):
+            def step(cols, pol_idx, args, policy_params):
+                seen.append((pol_idx, args))
+                return cols, None, jnp.zeros(())
+            return step
+        monkeypatch.setattr(CH, "_compile_chunk_step", fake_step)
+    X.run_experiment(spec, chunk=chunk, mesh=mesh)
+    return seen
+
+
+@pytest.mark.parametrize("kind", ["flat", "scenario", "workflow",
+                                  "streaming"])
+@pytest.mark.parametrize("chunk", [None, 8])
+@pytest.mark.parametrize("sharded", [False, True])
+def test_executable_inputs_match_device_constructors(kind, chunk, sharded,
+                                                     monkeypatch):
+    """Host-built, placed once: every executable call sees inputs
+    bitwise, dtype-, shape- and sharding-equal to those the device
+    constructors built, monolithic and chunked, with and without a
+    mesh (one device here)."""
+    from repro.launch.mesh import make_local_mesh
+    spec = SPECS[kind]().with_(n_replicas=24)
+    mesh = make_local_mesh(data=1, model=1) if sharded else None
+    got = _executable_inputs(spec, chunk, mesh, monkeypatch)
+    _device_constructors(monkeypatch)
+    want = _executable_inputs(spec, chunk, mesh, monkeypatch)
+    assert len(got) == len(want) == (1 if chunk is None else 3)
+    for g, w in zip(got, want):
+        assert jax.tree.structure(g) == jax.tree.structure(w)
+        for x, y in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+            assert isinstance(x, jax.Array) and isinstance(y, jax.Array)
+            assert (x.dtype, x.shape) == (y.dtype, y.shape)
+            assert x.sharding == y.sharding
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("kind", ["flat", "streaming"])
+@pytest.mark.parametrize("chunked", [False, True])
+def test_stack_span_counts_one_transfer_per_leaf(tmp_path, kind, chunked,
+                                                 monkeypatch):
+    """The ``stack`` span that places the inputs records one transfer
+    per input leaf whatever the replica count, and bytes that scale
+    with it (a call, or a chunk, of 8 and of 32 replicas)."""
+    spans = {}
+    for n in (8, 32):
+        log = TL.enable(str(tmp_path / str(n)))
+        try:
+            inputs = _executable_inputs(
+                SPECS[kind]().with_(n_replicas=n), n if chunked else None,
+                None, monkeypatch)
+        finally:
+            TL.disable()
+        spans[n] = [r for r in TL.read_jsonl(log.path)
+                    if r["kind"] == "span" and "transfers" in r]
+        assert len(spans[n]) == len(inputs)
+        for sp, leaves in zip(spans[n], inputs):
+            assert sp["name"] == "stack"
+            assert sp["transfers"] == len(jax.tree.leaves(leaves))
+            assert sp["h2d_bytes"] == sum(x.nbytes for x in
+                                          jax.tree.leaves(leaves))
+    small, big = spans[8][0], spans[32][0]
+    assert small["transfers"] == big["transfers"]
+    assert big["h2d_bytes"] == 4 * small["h2d_bytes"]
+
+
+# ---------------------------------------------------------------------------
 # Peak memory: O(chunk), not O(R)
 # ---------------------------------------------------------------------------
 def _live_bytes() -> int:
