@@ -2,9 +2,10 @@
 
 After the window closes, a sample of the replicas that the timed calls
 finished, drawn from the seed (``per_policy`` replicas of every policy
-the cell runs, over all its calls), is simulated again by
-``reference.py`` from inputs drawn by ``inputs.py``, both independent
-of the program.  Four numbers are compared, each with its limit:
+the cell runs, over all its calls, by the family's layout), is simulated
+again by the configuration's family (``families/<family>.py``: its
+``draw`` and ``simulate``), independent of the program.  Four numbers
+are compared, each with its limit:
 
   count_gap    largest |program - reference| in a replica's count of
                completed, missed, cancelled, preempted or requeued tasks
@@ -25,8 +26,7 @@ import os
 
 import numpy as np
 
-from bench import inputs as I
-from bench import reference as R
+from bench import harness as H
 
 #: each limit lies between the largest reading of sound runs of the
 #: program and the smallest reading of the bfloat16 control (PERF.md)
@@ -37,18 +37,11 @@ TERMINAL = ("completed", "missed", "cancelled", "preempted")
 MAX_WORKERS = 12
 
 
-def replica_policies(axes: dict, n_replicas: int) -> np.ndarray:
-    """(R,) index into ``axes["policies"]`` of every replica of a call."""
-    r = np.arange(n_replicas)
-    n_fd = len(axes["fail_rates"]) * len(axes["dvfs_states"])
-    return (r // n_fd) % len(axes["policies"])
-
-
-def draw_sample(axes: dict, n_replicas: int, n_calls: int, per_policy: int,
-                seed: int) -> list[tuple[int, int]]:
+def draw_sample(family, axes: dict, n_replicas: int, n_calls: int,
+                per_policy: int, seed: int) -> list[tuple[int, int]]:
     """(call, replica) pairs: ``per_policy`` of each policy, from the seed."""
     rng = np.random.default_rng([seed, 0xC4EC])
-    pol = replica_policies(axes, n_replicas)
+    pol = family.replica_policies(axes, n_replicas)
     out = []
     for p in range(len(axes["policies"])):
         cand = [(c, int(r)) for c in range(n_calls)
@@ -60,17 +53,20 @@ def draw_sample(axes: dict, n_replicas: int, n_calls: int, per_policy: int,
 
 
 def _ref_row(job):
-    config, axes, seed, r, window, precision = job
-    inp, policy = I.draw(config, axes, seed, r)
-    return R.simulate(inp, policy, window=window, precision=precision)
+    bench_dir, config, axes, seed, r, window, precision = job
+    family = H.family(config, bench_dir)
+    inp, policy = family.draw(config, axes, seed, r)
+    return family.simulate(inp, policy, window=window, precision=precision)
 
 
 def reference_rows(config: dict, axes: dict, jobs: list[tuple[int, int]],
                    window: int | None, precision: str,
                    workers: int | None = None) -> list[dict]:
     """The reference's row for each (seed, replica) job, on host workers
-    that import only numpy (spawned, so none touches the chip)."""
-    todo = [(config, axes, s, r, window, precision) for s, r in jobs]
+    that load the configuration's family by name and import only numpy
+    (spawned, so none touches the chip)."""
+    todo = [(H.BENCH_DIR, config, axes, s, r, window, precision)
+            for s, r in jobs]
     workers = workers or max(1, min(len(todo), (os.cpu_count() or 2) - 1,
                                     MAX_WORKERS))
     if workers == 1:
@@ -82,21 +78,21 @@ def reference_rows(config: dict, axes: dict, jobs: list[tuple[int, int]],
     return out
 
 
-def row_gaps(prog: dict, ref: dict) -> tuple[float, float]:
+def row_gaps(family, prog: dict, ref: dict) -> tuple[float, float]:
     """(count gap, relative value gap) between one program row and the
     reference's row for the same replica."""
-    cg = [abs(float(prog[k]) - ref[k]) for k in R.COUNT_COLUMNS]
+    cg = [abs(float(prog[k]) - ref[k]) for k in family.COUNT_COLUMNS]
     vg = [abs(float(prog[k]) - ref[k]) / max(abs(ref[k]), 1e-3)
-          for k in R.VALUE_COLUMNS]
+          for k in family.VALUE_COLUMNS]
     # a missing or NaN answer is as far off as it gets
     return (float(np.max(cg)) if np.all(np.isfinite(cg)) else math.inf,
             float(np.max(vg)) if np.all(np.isfinite(vg)) else math.inf)
 
 
-def fold_gaps(rows: dict, agg, axes: dict, n_replicas: int
+def fold_gaps(family, rows: dict, agg, axes: dict, n_replicas: int
               ) -> tuple[float, float]:
     """(replica-count gap, relative fold gap) of one call's ``SweepAgg``."""
-    pol = replica_policies(axes, n_replicas)
+    pol = family.replica_policies(axes, n_replicas)
     count_gap = 0.0
     gap = 0.0
     for p, name in enumerate(axes["policies"]):
@@ -125,11 +121,12 @@ def compare(calls: list, config: dict, traffic: dict, seed: int,
     (None where the path folds none).  The reference runs in the
     precision the configuration states.  ``control`` names a lower
     precision in which the reference takes the program's place."""
-    axes = I.cell_axes(config, traffic)
+    family = H.family(config)
+    axes = family.axes(config, traffic)
     n_rep = traffic["replicas"]
     window = traffic.get("streaming")
-    sample = draw_sample(axes, n_rep, len(calls), traffic["check_per_policy"],
-                         seed)
+    sample = draw_sample(family, axes, n_rep, len(calls),
+                         traffic["check_per_policy"], seed)
     jobs = [(calls[c][0], r) for c, r in sample]
     refs = reference_rows(config, axes, jobs, window, config["precision"],
                           workers)
@@ -139,12 +136,13 @@ def compare(calls: list, config: dict, traffic: dict, seed: int,
             rows = calls[c][1]
             progs.append({k: np.asarray(rows[k])[r]
                           if r < len(rows[k]) else np.nan
-                          for k in R.COUNT_COLUMNS + R.VALUE_COLUMNS})
+                          for k in family.COUNT_COLUMNS
+                          + family.VALUE_COLUMNS})
     else:
         progs = reference_rows(config, axes, jobs, window, control, workers)
     out = {"count_gap": 0.0, "value_gap": 0.0, "replica_gap": 0.0}
     for prog, ref in zip(progs, refs):
-        cg, vg = row_gaps(prog, ref)
+        cg, vg = row_gaps(family, prog, ref)
         out["count_gap"] = max(out["count_gap"], cg)
         out["value_gap"] = max(out["value_gap"], vg)
     if control is None:
@@ -152,7 +150,7 @@ def compare(calls: list, config: dict, traffic: dict, seed: int,
             out["replica_gap"] += abs(n_rep - min(len(v) for v in
                                                   rows.values()))
             if agg is not None:
-                cg, fg = fold_gaps(rows, agg, axes, n_rep)
+                cg, fg = fold_gaps(family, rows, agg, axes, n_rep)
                 out["replica_gap"] += cg
                 out["fold_gap"] = max(out.get("fold_gap", 0.0), fg)
     for k, v in out.items():

@@ -1,4 +1,5 @@
-"""The benchmark's own copy of how a replica's inputs follow from a seed.
+"""The benchmark's own copy of how a replica's inputs follow from a seed,
+for the ``independent`` family (``families/independent.py``).
 
 The program draws every replica of a call from the call's seed
 (``launch/experiment.py``: ``normalize``).  The reference must take
@@ -19,18 +20,6 @@ DVFS_STATES = {"nominal": (1.00, 1.00), "balanced": (0.80, 0.55),
                "powersave": (0.60, 0.30), "turbo": (1.20, 1.60)}
 INCONSISTENCY = 0.3
 SLACK = 4.0
-
-
-def cell_axes(config: dict, traffic: dict) -> dict:
-    """The cell's grid axes: the config's scenario, narrowed by traffic."""
-    scen = dict(config["scenario"], **traffic.get("scenario", {}))
-    return {"fail_rates": list(scen["fail_rates"]),
-            "dvfs_states": list(scen["dvfs_states"]),
-            "spot_frac": float(scen["spot_frac"]),
-            "mttr": float(scen["mttr"]),
-            "n_intervals": int(scen["n_intervals"]),
-            "policies": list(traffic["policies"]),
-            "arrivals": list(traffic["arrivals"])}
 
 
 def grid_cell(axes: dict, r: int) -> dict:

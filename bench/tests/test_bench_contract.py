@@ -55,6 +55,19 @@ def test_cell_resolves(cell):
         assert reader.read({"traffic": res["traffic"]}) is None
 
 
+@pytest.mark.parametrize("cell", CELLS)
+def test_cell_family_exposes_the_interface(cell):
+    fam = H.resolve(cell)["family"]
+    assert H.FAMILY_API == ("axes", "make_spec", "replica_policies", "draw",
+                            "simulate", "COUNT_COLUMNS", "VALUE_COLUMNS")
+    for name in H.FAMILY_API[:5]:
+        assert callable(getattr(fam, name)), name
+    for name in H.FAMILY_API[5:]:
+        cols = getattr(fam, name)
+        assert isinstance(cols, tuple) and cols, name
+        assert all(isinstance(c, str) for c in cols)
+
+
 def test_names_units_and_entries():
     names = []
     for c in BENCH["configs"]:

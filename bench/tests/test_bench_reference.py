@@ -10,6 +10,7 @@ from bench import check
 from bench import harness as H
 from bench import inputs as I
 from bench import reference as R
+from bench.families import independent as F
 from repro.core import ref_engine as RE
 from repro.core import schedulers as P
 from repro.launch import experiment as X
@@ -49,8 +50,8 @@ def _program_inputs(reps, r):
 
 @pytest.mark.parametrize("block", range(4))
 def test_draws_equal_the_programs_normalize(program_replicas, block):
-    axes = I.cell_axes(CONFIG, TRAFFIC)
-    pol = check.replica_policies(axes, TRAFFIC["replicas"])
+    axes = F.axes(CONFIG, TRAFFIC)
+    pol = F.replica_policies(axes, TRAFFIC["replicas"])
     for r in range(block * 20, block * 20 + 20):
         mine, policy = I.draw(CONFIG, axes, SEED, r)
         theirs, pid = _program_inputs(program_replicas, r)
@@ -73,7 +74,7 @@ def _oracle(inp, policy, window=None):
 @pytest.mark.parametrize("policy", R.HEURISTICS)
 @pytest.mark.parametrize("window", [None, 8])
 def test_reference_matches_the_oracle(policy, window):
-    axes = I.cell_axes(CONFIG, dict(TRAFFIC, policies=[policy]))
+    axes = F.axes(CONFIG, dict(TRAFFIC, policies=[policy]))
     for r in (0, 1, 2, 3, 5):
         inp, pol = I.draw(CONFIG, axes, SEED + 3, r)
         mine = R.Replica(inp, pol, window=window).run()
@@ -86,11 +87,12 @@ def test_reference_matches_the_oracle(policy, window):
 
 
 def test_bfloat16_control_departs_from_the_reference():
-    axes = I.cell_axes(CONFIG, TRAFFIC)
+    axes = F.axes(CONFIG, TRAFFIC)
     worst = 0.0
     for r in range(0, 40, 3):
         inp, pol = I.draw(CONFIG, axes, SEED, r)
-        cg, vg = check.row_gaps(R.simulate(inp, pol, precision="bfloat16"),
+        cg, vg = check.row_gaps(F,
+                                R.simulate(inp, pol, precision="bfloat16"),
                                 R.simulate(inp, pol, precision="float32"))
         worst = max(worst, vg)
     assert worst > check.LIMITS["value_gap"]
@@ -107,11 +109,12 @@ def test_float32_reference_follows_the_program_where_float64_parts():
     rows = X.run_experiment(H.make_spec(cfg, traffic, seed)).metrics
     prog = {k: np.asarray(rows[k])[r]
             for k in R.COUNT_COLUMNS + R.VALUE_COLUMNS}
-    inp, pol = I.draw(cfg, I.cell_axes(cfg, traffic), seed, r)
+    inp, pol = I.draw(cfg, F.axes(cfg, traffic), seed, r)
     window = traffic["streaming"]
-    cg32, vg32 = check.row_gaps(prog, R.simulate(inp, pol, window=window,
-                                                 precision="float32"))
-    cg64, _ = check.row_gaps(prog, R.simulate(inp, pol, window=window))
+    cg32, vg32 = check.row_gaps(F, prog,
+                                R.simulate(inp, pol, window=window,
+                                           precision="float32"))
+    cg64, _ = check.row_gaps(F, prog, R.simulate(inp, pol, window=window))
     assert cg32 <= check.LIMITS["count_gap"]
     assert vg32 <= check.LIMITS["value_gap"]
     assert cg64 > check.LIMITS["count_gap"]
