@@ -87,7 +87,7 @@ def save_eet_csv(table: EETTable, path: str) -> None:
 def synth_eet(n_task_types: int, n_machine_types: int, *,
               task_var: float = 1.0, machine_var: float = 0.5,
               inconsistency: float = 0.2, base: float = 1.0,
-              seed: int = 0) -> EETTable:
+              seed: int = 0, costs: np.ndarray | None = None) -> EETTable:
     """Synthesize an EET matrix with controlled heterogeneity.
 
     Uses the classic CVB (coefficient-of-variation based) style construction:
@@ -95,9 +95,14 @@ def synth_eet(n_task_types: int, n_machine_types: int, *,
     lognormal inconsistency noise.  ``inconsistency=0`` gives a *consistent*
     heterogeneous system (machine ordering identical for every task type);
     larger values mix the orderings (the interesting regime for MinMin etc.).
+    ``costs`` (optional, (T,)) fixes each task type's cost, as a job
+    type's runtime profile does; the machine factors and the noise are
+    the draws the seed gives either way.
     """
     rng = np.random.default_rng(seed)
     task_cost = base * rng.lognormal(0.0, task_var, size=(n_task_types, 1))
+    if costs is not None:
+        task_cost = base * np.asarray(costs, np.float64).reshape(-1, 1)
     machine_slow = rng.lognormal(0.0, machine_var, size=(1, n_machine_types))
     noise = rng.lognormal(0.0, inconsistency,
                           size=(n_task_types, n_machine_types))

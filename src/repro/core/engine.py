@@ -18,9 +18,11 @@ Event ordering within a timestamp `t` (matches the E2C loop):
   2b. dependency release (workflow mode only: refresh each task's
      remaining-parents counter from the status column; tasks whose
      parents all terminated but not all *completed* can never run and
-     are cancelled — cascades resolve to a fixpoint within the phase),
-  3. arrivals     (``arrival <= t`` AND all parents completed -> batch
-     queue, overflow -> cancelled),
+     are cancelled — cascades resolve to a fixpoint within the phase;
+     then each task's staging-release time, the latest parent end plus
+     its edge's staging delay),
+  3. arrivals     (``arrival <= t`` AND all parents completed AND every
+     input staged -> batch queue, overflow -> cancelled),
   4. deadline drops (queued -> MISSED_QUEUE, running -> MISSED_RUNNING and
      the machine is freed; partial energy is charged),
   5. scheduler drain (policy picks (task, machine) pairs until no room / no
@@ -29,12 +31,15 @@ Event ordering within a timestamp `t` (matches the E2C loop):
   6. start tasks on idle *available* machines (lowest mapping-sequence
      first — FIFO within a machine queue, E2C's sequential execution).
 
-Workflows: ``run_sim(..., parents=(N, K) int32)`` makes task precedence
-first-class — a task's effective arrival is ``max(arrival, completion of
-all parents)``.  The static ``has_deps`` choice is a Python-level
-``parents is None`` check (like tracing), so independent-task mode
-compiles the identical HLO it compiled before DAGs existed.  See
-docs/workflows.md.
+Workflows: ``run_sim(..., parents=state.Edges)`` makes task precedence
+first-class — a task's effective arrival is ``max(arrival, max over its
+in-edges of parent end + staging delay)``, and precedence costs
+O(E log E) work in log2(E) steps an event, where a padded table costs
+O(N·K_max).  (A padded (N, K) parent table, as the streaming window keeps,
+is also accepted and means zero delays.)  The static ``has_deps`` choice
+is a Python-level ``parents is None`` check (like tracing), so
+independent-task mode compiles the identical HLO it compiled before DAGs
+existed.  See docs/workflows.md.
 
 DVFS: each machine's ``speed`` divides its EET row (both the scheduler's
 expectations and actual runtimes) and ``power_scale`` multiplies its
@@ -223,7 +228,7 @@ def _availability(st: S.SimState, tb: S.StaticTables,
 
 
 @jax.named_scope("release")
-def _release(st: S.SimState, parents: jnp.ndarray) -> S.SimState:
+def _release(st: S.SimState, parents) -> S.SimState:
     """Workflow-mode phase: refresh dependency state, cancel dead branches.
 
     Runs between availability and arrivals.  The remaining-parents
@@ -234,6 +239,9 @@ def _release(st: S.SimState, parents: jnp.ndarray) -> S.SimState:
     constraint.  Cancelling such a task may doom its own children, so
     the phase iterates to a fixpoint (each trip resolves one cascade
     level; the loop is bounded by the not-yet-arrived population).
+    With an edge list (``state.Edges``) each trip is O(E log E) work in
+    log2(E) steps, and after the fixpoint ``SimState.ready_at`` takes
+    each task's staging-release time (``state.ready_times``).
 
     Tracing note: like the drain loop, cascade cancels are recorded once
     per event via a status diff (task-id order), keeping the buffers out
@@ -266,6 +274,8 @@ def _release(st: S.SimState, parents: jnp.ndarray) -> S.SimState:
     # deps_left is current: the loop only exits on a pass that changed
     # nothing, so the last stored counters reflect the final statuses
     # (the arrivals phase reads deps_left == 0 as "all parents completed")
+    if st.ready_at is not None:
+        st = replace(st, ready_at=S.ready_times(st.tasks.t_end, parents))
     return replace(st, trace=trace)
 
 
@@ -275,6 +285,8 @@ def _arrivals(st: S.SimState, qcap: int) -> S.SimState:
     new = (tasks.status == S.NOT_ARRIVED) & (tasks.arrival <= st.time)
     if st.deps_left is not None:
         new = new & (st.deps_left == 0)
+    if st.ready_at is not None:
+        new = new & (st.ready_at <= st.time)
     # batch-queue population from the incremental counter — the former
     # O(N) status scan was paid on every event (docs/engine_perf.md)
     in_batch = st.n_batch
@@ -621,25 +633,37 @@ def _next_event_time(st: S.SimState,
             return _fold_transitions(t, st, dyn, transitions)
         t_arr = jnp.min(jnp.where(not_arrived, tasks.arrival, S.INF))
     else:
-        # a dependency-blocked task has no pending arrival event: its
-        # release rides on a parent's terminal transition, which is
-        # already an event candidate (completion / deadline / cancel).
-        left, failed = S.dep_state(tasks.status, parents)
-        t_arr = jnp.min(jnp.where(not_arrived & (left == 0) & ~failed,
-                                  tasks.arrival, S.INF))
-        # a parent that *failed* during phases 3-6 (overflow cancel,
-        # deadline drop, drain cancel) leaves a cascade pending after
-        # the release phase already ran — process it at the current
-        # timestamp so the doomed subtree terminates promptly.
-        pending = not_arrived & (left == 0) & failed
-        t_arr = jnp.minimum(t_arr, jnp.where(pending.any(), st.time,
-                                             S.INF))
+        t_arr = _next_release(st, parents)
     t_cmp = jnp.min(jnp.where(mach.running >= 0, mach.busy_until, S.INF))
     live = (tasks.status == S.IN_BATCH) | (tasks.status == S.IN_MQ) | (
         tasks.status == S.RUNNING)
     t_dl = jnp.min(jnp.where(live, tasks.deadline, S.INF))
     t = jnp.minimum(jnp.minimum(t_arr, t_cmp), t_dl)
     return _fold_transitions(t, st, dyn, transitions)
+
+
+@jax.named_scope("release")
+def _next_release(st: S.SimState, parents) -> jnp.ndarray:
+    """The earliest pending arrival of a task whose parents all
+    completed: its own arrival or, with staging delays, the time its
+    last input is staged (``SimState.ready_at``, current since the
+    release phase: no parent completes between it and the next event)."""
+    tasks = st.tasks
+    not_arrived = tasks.status == S.NOT_ARRIVED
+    # a dependency-blocked task has no pending arrival event: its
+    # release rides on a parent's terminal transition, which is
+    # already an event candidate (completion / deadline / cancel).
+    left, failed = S.dep_state(tasks.status, parents)
+    t_eff = tasks.arrival if st.ready_at is None else jnp.maximum(
+        tasks.arrival, st.ready_at)
+    t_arr = jnp.min(jnp.where(not_arrived & (left == 0) & ~failed,
+                              t_eff, S.INF))
+    # a parent that *failed* during phases 3-6 (overflow cancel,
+    # deadline drop, drain cancel) leaves a cascade pending after
+    # the release phase already ran — process it at the current
+    # timestamp so the doomed subtree terminates promptly.
+    pending = not_arrived & (left == 0) & failed
+    return jnp.minimum(t_arr, jnp.where(pending.any(), st.time, S.INF))
 
 
 def _fold_transitions(t, st, dyn, transitions):
@@ -679,10 +703,12 @@ def run_sim(tasks: S.TaskTable, mtype: jnp.ndarray, tables: S.StaticTables,
     (optional) carries learned-policy weights (``neural.PolicyParams``) —
     when omitted the zero default is used, so heuristic runs need not
     build one; vmapping this axis evaluates a *population* of policies
-    (core/train_policy.py).  ``parents`` (optional, (N, K) int32 padded
-    with -1) adds workflow precedence constraints — a task arrives only
-    once every parent completed (docs/workflows.md); omitting it
-    compiles the independent-task engine with zero DAG overhead.
+    (core/train_policy.py).  ``parents`` (optional: a ``state.Edges``
+    edge list with staging delays, or an (N, K) int32 parent table
+    padded with -1, which means zero delays) adds workflow precedence
+    constraints — a task arrives only once every parent completed and
+    its inputs are staged (docs/workflows.md); omitting it compiles the
+    independent-task engine with zero DAG overhead.
     """
     if policy_params is None:
         policy_params = NN.default_params()
@@ -696,6 +722,9 @@ def run_sim(tasks: S.TaskTable, mtype: jnp.ndarray, tables: S.StaticTables,
     if parents is not None and params.max_events is None:
         # every failure-release cascade echoes at most one extra event
         # per cancelled task (same-timestamp re-entry)
+        max_events += n
+    if isinstance(parents, S.Edges) and params.max_events is None:
+        # a staged release is an event of its own, one per task
         max_events += n
     if params.trace:
         k = dynamics.down_start.shape[-1] if dynamics is not None else 0
@@ -798,9 +827,10 @@ def simulate(workload, eet: EETTable, power: np.ndarray,
     """Host-friendly wrapper: one replica, named policy.
 
     ``workload`` is a ``workload.Workload`` (independent tasks) or a
-    ``workload.Workflow`` (DAG) — the latter threads its parent table
-    into the engine's dependency-release phase and precomputes the HEFT
-    upward ranks from the EET row means (docs/workflows.md).
+    ``workload.Workflow`` (DAG) — the latter threads its edge list and
+    staging delays into the engine's dependency-release phase and
+    precomputes the HEFT upward ranks from the EET row means
+    (docs/workflows.md).
     ``dynamics`` makes the fleet dynamic (failures / spot preemption /
     DVFS) — build one with ``workload.Scenario.dynamics()`` or
     ``state.static_dynamics``.  ``trace=True`` attaches a
@@ -819,7 +849,10 @@ def simulate(workload, eet: EETTable, power: np.ndarray,
     parents = rank = None
     if isinstance(workload, Workflow):
         eet_arr = eet.eet if isinstance(eet, EETTable) else np.asarray(eet)
-        parents = jnp.asarray(workload.parents, jnp.int32)
+        # pad the edge list to a multiple of 64 so DAGs of similar size
+        # share one compiled engine (padding edges lie in no task's range)
+        parents = jax.device_put(workload.host_edges(
+            -(-max(workload.n_edges, 1) // 64) * 64))
         rank = workload.ranks(np.asarray(eet_arr).mean(axis=1))
         workload = workload.workload
     params = SimParams(lcap=lcap, qcap=qcap or (1 << 30),
@@ -849,8 +882,9 @@ def run_sweep(tasks: S.TaskTable, mtype: jnp.ndarray,
     Monte-Carlo grid over failure rates / DVFS states is just another
     stacked input.  So does ``policy_params``: stacking perturbed weight
     pytrees along the replica axis evaluates a whole ES population in one
-    call (core/train_policy.py).  And so does ``parents`` ((R, N, K)):
-    a grid over workflow DAG shapes is one more stacked axis.  Optional
+    call (core/train_policy.py).  And so does ``parents`` (an
+    ``Edges`` with (R, E) leaves, or (R, N, K)): a grid over workflow
+    DAG shapes is one more stacked axis.  Optional
     inputs left as ``None`` compile their feature out of every replica,
     exactly as in ``run_sim`` (None is an empty pytree under vmap).
     """

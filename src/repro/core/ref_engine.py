@@ -8,6 +8,15 @@ this reference produce identical task lifecycles on random instances.
 Tie-breaking rules are deliberately identical to the JAX engine:
 lowest task id first, lowest machine id first, row-major (task-major) for
 pair policies.
+
+Times are float64 by default.  With ``precision="float32"`` every
+computed time is rounded to float32 as the engine computes it:
+execution and expected times, completion times, machine availability
+(queued work summed once an event, then one add per mapping, as the
+engine's drain carries it), completion estimates and staging-release
+times.  Two tasks that end one float32 ulp apart are then two events
+here as in the engine, where float64 would see one.  Energies stay
+float64.
 """
 from __future__ import annotations
 
@@ -21,6 +30,11 @@ from repro.core import state as S
 from repro.core import trace as TR
 
 BIG = 1e30
+
+
+def _f32(x) -> float:
+    """``x`` rounded to float32, as the engine's arithmetic is."""
+    return float(np.float32(x))
 
 
 @dataclass
@@ -65,8 +79,9 @@ class _Sim:
     # learned-policy weights (numpy float32 dict from neural.params_to_numpy;
     # None = the engine's zero default)
     policy_params: dict | None = None
-    # workflow mode (see engine._release / docs/workflows.md)
-    parents: np.ndarray | None = None        # (N, K) i32, -1 padded
+    # workflow mode (see engine._release / docs/workflows.md): the edge
+    # list (src, dst, staging delay), or None for independent tasks
+    edges: tuple | None = None
     rank: np.ndarray | None = None           # (N,) HEFT upward ranks
     # streaming mode (see core/streaming.py / docs/streaming.md): at most
     # ``window`` tasks are live at once; the rest of the stream loads in
@@ -76,6 +91,8 @@ class _Sim:
     # a queue-depth sample per processed event, per-task histograms +
     # SLO windows folded over the final table.  None = uninstrumented.
     metrics_spec: ME.MetricsSpec | None = None
+    # "float64", or "float32": round every computed time as the engine
+    precision: str = "float64"
 
     status: np.ndarray = field(init=False)
     machine: np.ndarray = field(init=False)
@@ -122,23 +139,35 @@ class _Sim:
         self.loaded = np.full(n, self.window is None, bool)
         self.retired = np.zeros(n, bool)
         self.children: dict[int, list[int]] = {}
-        if self.parents is not None:
-            for t in range(n):
-                for p in self.parents[t]:
-                    if p >= 0:
-                        self.children.setdefault(int(p), []).append(t)
+        self.in_edges: list[list[tuple[int, float]]] = [[] for _ in range(n)]
+        if self.edges is not None:
+            for p, c, d in zip(*self.edges):
+                self.children.setdefault(int(p), []).append(int(c))
+                self.in_edges[int(c)].append((int(p), float(d)))
+        self._avail: dict[int, float] = {}
+        if self.precision not in ("float64", "float32"):
+            raise ValueError(f"precision {self.precision!r}")
+        self.f32 = self.precision == "float32"
+        self.r = _f32 if self.f32 else float
 
     # ---- helpers ---------------------------------------------------------
     def exec_time(self, t: int, m: int) -> float:
-        return float(self.eet[self.type_id[t], self.mtype[m]]
-                     * self.noise[t] / self.speed[m])
+        r = self.r
+        return r(r(self.eet[self.type_id[t], self.mtype[m]]
+                   * self.noise[t]) / self.speed[m])
 
     def expected(self, t: int, m: int) -> float:
-        return float(self.eet[self.type_id[t], self.mtype[m]]
-                     / self.speed[m])
+        return self.r(self.eet[self.type_id[t], self.mtype[m]]
+                      / self.speed[m])
 
     def p_active(self, m: int) -> float:
-        return float(self.power[self.mtype[m], 1] * self.power_scale[m])
+        return self.r(self.power[self.mtype[m], 1] * self.power_scale[m])
+
+    def completion(self, t: int, m: int) -> float:
+        return self.r(self._avail[m] + self.expected(t, m))
+
+    def cost(self, t: int, m: int) -> float:
+        return self.r(self.expected(t, m) * self.p_active(m))
 
     def up(self, m: int) -> bool:
         return not np.any((self.down_start[m] <= self.time)
@@ -157,10 +186,20 @@ class _Sim:
         return len(self.queue_of(m)) < self.lcap
 
     def avail(self, m: int) -> float:
+        """Earliest start of new work on ``m``: its running task's end
+        (or now) plus its queued expected work — in float32 summed in
+        task-id order and added once, as the engine's drain computes it
+        each event."""
         base = self.time
         if self.running[m] >= 0:
             base = max(base, self.busy_until[m])
-        return base + sum(self.expected(t, m) for t in self.queue_of(m))
+        if not self.f32:
+            return base + sum(self.expected(t, m) for t in self.queue_of(m))
+        queued = 0.0
+        for t in np.nonzero((self.status == S.IN_MQ)
+                            & (self.machine == m))[0]:
+            queued = _f32(queued + self.expected(t, m))
+        return _f32(base + queued)
 
     def batch_queue(self) -> list[int]:
         return list(np.nonzero(self.status == S.IN_BATCH)[0])
@@ -255,9 +294,13 @@ class _Sim:
                 self.seq[t] = np.iinfo(np.int32).max
 
     def _parents_of(self, t: int) -> list[int]:
-        if self.parents is None:
-            return []
-        return [int(p) for p in self.parents[t] if p >= 0]
+        return [p for p, _ in self.in_edges[t]]
+
+    def ready(self, t: int) -> float:
+        """When task ``t``'s last input file is staged: the latest
+        parent end plus that edge's delay (-inf without parents)."""
+        return max((self.r(self.t_end[p] + d) for p, d in self.in_edges[t]),
+                   default=-np.inf)
 
     def released(self, t: int) -> bool:
         """All parents terminal (workflow mode; trivially true without)."""
@@ -274,7 +317,7 @@ class _Sim:
         whose precedence constraint can never be satisfied, cascading to
         a fixpoint; cancels are emitted once, in task-id order, exactly
         like the engine's status-diff record."""
-        if self.parents is None:
+        if self.edges is None:
             return
         cancelled: list[int] = []
         changed = True
@@ -294,7 +337,8 @@ class _Sim:
     def arrivals(self):
         new = np.nonzero((self.status == S.NOT_ARRIVED) & self.loaded
                          & (self.arrival <= self.time))[0]
-        new = [t for t in new if self.released(t)]
+        new = [t for t in new
+               if self.released(t) and self.ready(t) <= self.time]
         n_in_batch = int((self.status == S.IN_BATCH).sum())
         for k, t in enumerate(sorted(new)):
             if n_in_batch + k + 1 <= self.qcap:
@@ -332,7 +376,7 @@ class _Sim:
                            np.float32)
         en_row = np.array([self.expected(t, m) * self.p_active(m)
                            for m in range(n_m)], np.float32)
-        avail = np.array([self.avail(m) for m in range(n_m)], np.float32)
+        avail = np.array([self._avail[m] for m in range(n_m)], np.float32)
         mq = np.array([len(self.queue_of(m)) for m in range(n_m)],
                       np.float32)
         room = np.array([self.room(m) and self.up(m) for m in range(n_m)],
@@ -349,7 +393,7 @@ class _Sim:
         if not q or not rooms:
             return None
         head = q[0]
-        avail = {m: self.avail(m) for m in rooms}
+        avail = self._avail
         if self.policy in ("mlp", "linear"):
             scores = self._learned_scores(head)
             m = min(rooms, key=lambda m: (scores[m], m))
@@ -366,49 +410,45 @@ class _Sim:
         if self.policy == "met":
             m = min(rooms, key=lambda m: (self.expected(head, m), m))
             return head, m
+        ct = self.completion
         if self.policy == "mct":
-            m = min(rooms, key=lambda m: (avail[m] + self.expected(head, m),
-                                          m))
+            m = min(rooms, key=lambda m: (ct(head, m), m))
             return head, m
         if self.policy == "ee_met":
-            m = min(rooms, key=lambda m: (
-                self.expected(head, m) * self.p_active(m), m))
+            m = min(rooms, key=lambda m: (self.cost(head, m), m))
             return head, m
         if self.policy == "ee_mct":
             feas = [m for m in rooms
-                    if avail[m] + self.expected(head, m)
-                    <= self.deadline[head]]
+                    if ct(head, m) <= self.deadline[head]]
             if feas:
-                m = min(feas, key=lambda m: (
-                    self.expected(head, m) * self.p_active(m), m))
+                m = min(feas, key=lambda m: (self.cost(head, m), m))
             else:
-                m = min(rooms, key=lambda m: (
-                    avail[m] + self.expected(head, m), m))
+                m = min(rooms, key=lambda m: (ct(head, m), m))
             return head, m
         if self.policy == "minmin":
             best = min(((t, m) for t in q for m in rooms),
-                       key=lambda tm: (avail[tm[1]]
-                                       + self.expected(*tm), tm[0], tm[1]))
+                       key=lambda tm: (ct(*tm), tm[0], tm[1]))
             return best
         if self.policy == "maxmin":
             def best_for(t):
-                return min(rooms, key=lambda m: (avail[m]
-                                                 + self.expected(t, m), m))
-            t = max(q, key=lambda t: (avail[best_for(t)]
-                                      + self.expected(t, best_for(t)), -t))
+                return min(rooms, key=lambda m: (ct(t, m), m))
+            t = max(q, key=lambda t: (ct(t, best_for(t)), -t))
             return t, best_for(t)
         if self.policy == "edf_mct":
             t = min(q, key=lambda t: (self.deadline[t], t))
-            m = min(rooms, key=lambda m: (avail[m] + self.expected(t, m), m))
+            m = min(rooms, key=lambda m: (ct(t, m), m))
             return t, m
         if self.policy == "heft":
             t = max(q, key=lambda t: (self.rank[t], -t))
-            m = min(rooms, key=lambda m: (avail[m] + self.expected(t, m), m))
+            m = min(rooms, key=lambda m: (ct(t, m), m))
             return t, m
         raise ValueError(f"unknown policy {self.policy}")
 
     def drain(self):
         cancelled: list[int] = []
+        # float32: availability once an event, then carried with one add
+        # per mapping, as the engine's drain; float64: afresh each step
+        self._avail = {m: self.avail(m) for m in range(len(self.mtype))}
         while True:
             dec = self.decide()
             if dec is None:
@@ -416,7 +456,7 @@ class _Sim:
             t, m = dec
             rooms = [mm for mm in range(len(self.mtype))
                      if self.room(mm) and self.up(mm)]
-            best = min(self.avail(mm) + self.expected(t, mm) for mm in rooms)
+            best = min(self.completion(t, mm) for mm in rooms)
             if self.cancel_infeasible and best > self.deadline[t]:
                 cancelled.append(t)
                 self.status[t] = S.CANCELLED
@@ -427,6 +467,8 @@ class _Sim:
                 self.seq[t] = self.seq_counter
                 self.seq_counter += 1
                 self.rr_ptr = (m + 1) % len(self.mtype)
+                self._avail[m] = self.completion(t, m) if self.f32 \
+                    else self.avail(m)
         # engine.py records drain cancels once per event via a status
         # diff (task-id order), not per drain iteration — mirror that
         for t in sorted(cancelled):
@@ -441,7 +483,8 @@ class _Sim:
                     self.emit(TR.EV_START, t, m)
                     self.status[t] = S.RUNNING
                     self.t_start[t] = self.time
-                    self.busy_until[m] = self.time + self.exec_time(t, m)
+                    self.busy_until[m] = self.r(self.time
+                                                + self.exec_time(t, m))
                     self.running[m] = t
 
     # ---- loop ------------------------------------------------------------
@@ -449,13 +492,15 @@ class _Sim:
         cands = []
         waiting = np.nonzero((self.status == S.NOT_ARRIVED)
                              & self.loaded)[0]
-        if self.parents is None:
+        if self.edges is None:
             na = self.arrival[waiting]
         else:
             # dependency-blocked tasks have no pending arrival event (a
             # parent's terminal transition is already a candidate); a
+            # released one arrives once its inputs are staged; a
             # pending failure-release cascade fires at the current time
-            na = np.array([self.arrival[t] for t in waiting
+            na = np.array([max(self.arrival[t], self.ready(t))
+                           for t in waiting
                            if self.released(t) and not self.dep_failed(t)])
             if any(self.released(t) and self.dep_failed(t)
                    for t in waiting):
@@ -481,7 +526,7 @@ class _Sim:
         budget = max_events or (4 * n + 16
                                 + 2 * self.down_start.shape[-1]
                                 * len(self.mtype)
-                                + (n if self.parents is not None else 0))
+                                + (2 * n if self.edges is not None else 0))
         n_events = 0
         while not np.all(self.status >= S.COMPLETED) and budget > 0:
             self.stream_load()
@@ -527,9 +572,9 @@ def simulate_ref(arrival, type_id, deadline, eet, power, mtype, *,
                  speed=None, power_scale=None, down_start=None,
                  down_end=None, kill=None,
                  max_events=None, trace=False,
-                 policy_params=None, parents=None,
+                 policy_params=None, parents=None, edges=None,
                  rank=None, window=None, metrics=False,
-                 metrics_spec=None) -> RefResult:
+                 metrics_spec=None, precision="float64") -> RefResult:
     """Oracle run.  The ``speed``/``power_scale``/``down_*``/``kill``
     kwargs mirror ``state.MachineDynamics`` (all default to the static
     fleet).  ``trace=True`` collects the ``(time, kind, task, machine)``
@@ -537,16 +582,20 @@ def simulate_ref(arrival, type_id, deadline, eet, power, mtype, *,
     ``tests/test_trace.py`` asserts the two streams are identical.
     ``policy_params`` takes a ``neural.PolicyParams`` pytree (or the dict
     from ``neural.params_to_numpy``) for the learned ``mlp``/``linear``
-    policies; omitted = the engine's zero default.  ``parents``/``rank``
-    mirror ``run_sim(parents=...)`` + ``StaticTables.rank`` (workflow
-    mode — pass the *same* float32 ranks the engine gets, so the ``heft``
+    policies; omitted = the engine's zero default.  ``edges``
+    (``(src, dst, delay)``, as ``workload.Workflow`` holds them) or a
+    padded ``parents`` table (zero delays) + ``rank`` mirror
+    ``run_sim(parents=...)`` + ``StaticTables.rank`` (workflow mode —
+    pass the *same* float32 ranks the engine gets, so the ``heft``
     orderings agree bit-for-bit).  ``window=W`` enables the streaming
     mirror: at most W tasks are live at once, refilled in id order as
     slots retire — the oracle for ``streaming.run_stream`` when N > W.
     ``metrics=True`` mirrors ``SimParams(metrics=True)``: the returned
     ``RefResult.metrics`` counts dict (``metrics.fold_tasks_np`` schema,
     samples cast to float32 before bucketing) must equal the engine's
-    histograms bit-for-bit — ``tests/test_metrics.py`` asserts it."""
+    histograms bit-for-bit — ``tests/test_metrics.py`` asserts it.
+    ``precision="float32"`` rounds every computed time as the engine
+    does (module docstring), so float32 near-ties resolve alike."""
     arrival = np.asarray(arrival, np.float64)
     if noise is None:
         noise = np.ones(len(arrival))
@@ -564,9 +613,15 @@ def simulate_ref(arrival, type_id, deadline, eet, power, mtype, *,
                kill=None if kill is None else np.asarray(kill, bool),
                trace=[] if trace else None,
                policy_params=policy_params,
-               parents=None if parents is None
-               else np.asarray(parents, np.int32),
+               edges=edges if parents is None else _table_edges(parents),
                rank=_f64(rank), window=window,
                metrics_spec=(metrics_spec or ME.DEFAULT_SPEC) if metrics
-               else None)
+               else None, precision=precision)
     return sim.run(max_events)
+
+
+def _table_edges(parents) -> tuple:
+    """(src, dst, zero delay) of a padded (N, K) parent table."""
+    parents = np.asarray(parents, np.int32)
+    dst, slot = np.nonzero(parents >= 0)
+    return parents[dst, slot], dst, np.zeros(dst.shape)

@@ -19,6 +19,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # ---------------------------------------------------------------------------
 # Task lifecycle (matches the E2C GUI components; see DESIGN.md table).
@@ -169,6 +170,12 @@ class SimState:
     #                           COMPLETED) — the event loop's `cond` reads
     #                           this scalar instead of reducing the full
     #                           status column every trip
+    ready_at: Any = None      # f32 (N,) staging-release time per task
+    #                           (edge-list workflow mode, else None): the
+    #                           latest ``t_end[src] + delay`` over the
+    #                           task's in-edges, -inf without parents.
+    #                           Set by the release phase; a released task
+    #                           arrives once it is <= the event time
 
 
 @register_pytree
@@ -185,11 +192,69 @@ class StaticTables:
     #                         `heft` policy through SchedView.rank)
 
 
-def dep_state(status: jnp.ndarray, parents: jnp.ndarray
+@register_pytree
+@dataclasses.dataclass
+class Edges:
+    """Workflow precedence as an edge list, sorted by child (CSR order).
+
+    Edge ``e`` runs from parent ``src[e]`` to child ``dst[e]`` and holds
+    the staging delay ``delay[e]`` (the parent's output file over the
+    shared storage's bandwidth).  The in-edges of task ``i`` are
+    ``[end[i-1], end[i])`` (``end[-1]`` taken as 0).  Edges past
+    ``end[N-1]`` pad the list to a grid's widest edge count and lie in no
+    task's range (``src = -1``, ``dst = N``, ``delay = 0``).
+    """
+
+    src: jnp.ndarray        # i32 (E,) parent of each edge, -1 = padding
+    dst: jnp.ndarray        # i32 (E,) child, nondecreasing; N = padding
+    delay: jnp.ndarray      # f32 (E,) staging delay, simulated time units
+    pos: jnp.ndarray        # i32 (E,) the edge's index in its task's range
+    end: jnp.ndarray        # i32 (N,) one past each task's last in-edge
+
+
+def pad_edges(e: Edges, width: int) -> Edges:
+    """``e`` (numpy leaves) padded to ``width`` edges that lie in no
+    task's range — the shape a grid of DAGs shares."""
+    pad = width - e.src.shape[0]
+    if pad < 0:
+        raise ValueError(f"width {width} < {e.src.shape[0]} edges")
+    n = e.end.shape[0]
+    return Edges(src=np.concatenate([e.src, np.full(pad, -1, np.int32)]),
+                 dst=np.concatenate([e.dst, np.full(pad, n, np.int32)]),
+                 delay=np.concatenate([e.delay, np.zeros(pad, np.float32)]),
+                 pos=np.concatenate([e.pos, np.zeros(pad, np.int32)]),
+                 end=e.end)
+
+
+def _seg_scan(x: jnp.ndarray, pos: jnp.ndarray, op, fill) -> jnp.ndarray:
+    """Inclusive scan of ``op`` along the last axis within each CSR
+    range (``pos[e]``: edge ``e``'s index in its task's range): log2(E)
+    steps, each a shift by a power of two merged where it stays in the
+    range (Hillis–Steele); contiguous slices only, no scatter."""
+    width = x.shape[-1]
+    s = 1
+    while s < width:
+        shifted = jnp.concatenate(
+            [jnp.full(x.shape[:-1] + (s,), fill, x.dtype), x[..., :-s]], -1)
+        x = jnp.where(pos >= s, op(x, shifted), x)
+        s *= 2
+    return x
+
+
+def _seg_last(x: jnp.ndarray, end: jnp.ndarray, fill) -> jnp.ndarray:
+    """Each task's value at its last in-edge (``fill`` without one)."""
+    start = jnp.concatenate([jnp.zeros((1,), end.dtype), end[:-1]])
+    last = jnp.clip(end - 1, 0, x.shape[-1] - 1)
+    return jnp.where(end > start, x[..., last], fill)
+
+
+def dep_state(status: jnp.ndarray, parents
               ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Per-task dependency summary from the current status column.
 
-    ``parents`` is the fixed-width (N, K) parent table, padded with -1.
+    ``parents`` is an :class:`Edges` list (log2(E) steps of O(E)) or,
+    for the streaming window, a fixed-width (N, K) parent table padded
+    with -1.
     Returns ``(left, failed)``: ``left[i]`` counts parents of ``i`` not
     yet in a terminal state (the remaining-parents counter), and
     ``failed[i]`` is True when some parent terminated without completing
@@ -197,12 +262,44 @@ def dep_state(status: jnp.ndarray, parents: jnp.ndarray
     cancelled by the engine's release phase.
     """
     n = status.shape[0]
+    if isinstance(parents, Edges):
+        valid = parents.src >= 0
+        ps = status[jnp.clip(parents.src, 0, n - 1)]   # (E,)
+        term = valid & (ps >= COMPLETED)
+        counts = jnp.stack([(valid & ~term).astype(jnp.int32),
+                            (term & (ps != COMPLETED)).astype(jnp.int32)])
+        counts = _seg_scan(counts, parents.pos, jnp.add, 0)
+        left, n_failed = _seg_last(counts, parents.end, 0)
+        return left, n_failed > 0
     valid = parents >= 0
     ps = status[jnp.clip(parents, 0, n - 1)]          # (N, K)
     term = valid & (ps >= COMPLETED)
     left = jnp.sum(valid & ~term, axis=1).astype(jnp.int32)
     failed = jnp.any(term & (ps != COMPLETED), axis=1)
     return left, failed
+
+
+def ready_times(t_end: jnp.ndarray, edges: Edges) -> jnp.ndarray:
+    """(N,) the latest ``t_end[src] + delay`` over each task's in-edges
+    (-inf without any): when its last input file has been staged.  Only
+    meaningful where every parent completed.  A max does not difference,
+    so it is a segmented max scan over the dst-sorted edges, read at
+    each task's last edge."""
+    n = t_end.shape[0]
+    v = jnp.where(edges.src >= 0,
+                  t_end[jnp.clip(edges.src, 0, n - 1)] + edges.delay,
+                  -INF)
+    return _seg_last(_seg_scan(v, edges.pos, jnp.maximum, -INF),
+                     edges.end, -INF)
+
+
+def in_degree(parents) -> jnp.ndarray:
+    """(N,) i32 number of parents of each task."""
+    if isinstance(parents, Edges):
+        start = jnp.concatenate([jnp.zeros((1,), parents.end.dtype),
+                                 parents.end[:-1]])
+        return (parents.end - start).astype(jnp.int32)
+    return jnp.sum(parents >= 0, axis=1).astype(jnp.int32)
 
 
 def init_state(tasks: TaskTable, mtype: jnp.ndarray,
@@ -235,9 +332,11 @@ def init_state(tasks: TaskTable, mtype: jnp.ndarray,
         t_start=jnp.full((n,), -1.0, jnp.float32),
         t_end=jnp.full((n,), -1.0, jnp.float32),
     )
-    deps_left = None
+    deps_left = ready_at = None
     if parents is not None:
-        deps_left = jnp.sum(parents >= 0, axis=1).astype(jnp.int32)
+        deps_left = in_degree(parents)
+    if isinstance(parents, Edges):
+        ready_at = jnp.full((n,), -INF, jnp.float32)
     return SimState(
         time=jnp.float32(0.0),
         tasks=tasks,
@@ -250,6 +349,7 @@ def init_state(tasks: TaskTable, mtype: jnp.ndarray,
         deps_left=deps_left,
         n_batch=jnp.int32(0),
         n_live=jnp.int32(n),
+        ready_at=ready_at,
     )
 
 

@@ -708,13 +708,17 @@ def simulate_stream(workload, eet: EETTable | np.ndarray,
     the stream granularity (defaults to ``min(n_tasks, window)`` —
     results are invariant to it).  ``workload`` may be a ``Workload`` or
     a ``Workflow`` (DAG mode; the dependency frontier must fit the
-    window — docs/streaming.md).  Remaining kwargs match
-    ``engine.simulate``.
+    window, and edges carry no staging delay yet — docs/streaming.md).
+    Remaining kwargs match ``engine.simulate``.
     """
     from repro.core.workload import Workflow
     eet_arr = eet.eet if isinstance(eet, EETTable) else np.asarray(eet)
     parents = rank = None
     if isinstance(workload, Workflow):
+        if np.any(workload.delay > 0):
+            raise ValueError("the streaming window has no staging delays: "
+                             "run a workflow with staged edges through "
+                             "engine.simulate")
         parents = np.asarray(workload.parents, np.int32)
         rank = workload.ranks(np.asarray(eet_arr).mean(axis=1))
         workload = workload.workload

@@ -12,12 +12,14 @@ machine availability traces (fail/repair or spot preemption) and DVFS
 operating points — so one object describes everything that varies across
 a Monte-Carlo sweep cell (see ``launch/sim.py``).
 
-A :class:`Workflow` adds *precedence constraints*: a fixed-width parent
-table (``parents: (N, K) int32``, padded with -1) over a workload whose
-task ids are a topological order.  Generators cover the canonical DAG
-shapes — chains, fork–join, map–reduce, seeded random layered DAGs —
-and :func:`upward_ranks` precomputes the HEFT priority used by the
-``heft`` scheduling policy (docs/workflows.md).
+A :class:`Workflow` adds *precedence constraints*: an edge list sorted by
+child, each edge carrying the parent's output file (staged over a shared
+storage before the child may run), over a workload whose task ids are a
+topological order.  Generators cover the canonical DAG shapes — chains,
+fork–join, map–reduce, seeded random layered DAGs — and the Pegasus
+scientific workflows (Montage, CyberShake, Epigenomics), and
+:func:`upward_ranks` precomputes the HEFT priority used by the ``heft``
+scheduling policy (docs/workflows.md).
 """
 from __future__ import annotations
 
@@ -291,37 +293,65 @@ def resolve_arrivals(names) -> tuple[str, ...]:
 # ---------------------------------------------------------------------------
 @dataclass
 class Workflow:
-    """A precedence-constrained workload: tasks + a fixed-width DAG.
+    """A precedence-constrained workload: tasks + a DAG as an edge list.
 
-    ``parents[i, k]`` lists the tasks that must *complete* before task
-    ``i`` may enter the system (its effective arrival is
-    ``max(arrival[i], completion of all parents)``); unused slots are
-    padded with -1.  Task ids must be a topological order
-    (``parents[i, k] < i``) — every generator below guarantees it, and
-    it is what lets :func:`upward_ranks` run in one reverse sweep.
+    Edge ``e`` runs from ``src[e]`` to ``dst[e]``: task ``dst[e]`` may
+    enter the system only once ``src[e]`` *completed* and its output
+    file (``nbytes[e]`` MB) has been staged over the shared storage
+    (``bandwidth`` MB per simulated time unit; None = no staging cost).
+    A task's effective arrival is ``max(arrival[i], max over in-edges of
+    t_end[src] + delay)``.  Edges are sorted by ``dst`` (CSR order, the
+    engine's ``state.Edges``), and task ids must be a topological order
+    (``src[e] < dst[e]``) — every generator below guarantees it, and it
+    is what lets :func:`upward_ranks` run in one reverse sweep.
 
     IMPORTANT: ``Workload`` sorts tasks by arrival time on construction.
-    Parent ids index the *sorted* order, so a workflow's arrival times
+    Edge ids index the *sorted* order, so a workflow's arrival times
     must be nondecreasing in task id (the generators emit a common
     submission time ``t0``, which trivially satisfies this).
     """
 
     workload: Workload
-    parents: np.ndarray     # (N, K) i32, -1 padded, parents[i, k] < i
+    src: np.ndarray                 # (E,) i32 parent
+    dst: np.ndarray                 # (E,) i32 child, nondecreasing
+    nbytes: np.ndarray | None = None    # (E,) f32 MB staged; None = 0
+    bandwidth: float | None = None  # MB per simulated time unit
 
     def __post_init__(self):
-        self.parents = np.asarray(self.parents, np.int32)
-        if self.parents.ndim != 2 or \
-                self.parents.shape[0] != self.workload.n_tasks:
-            raise ValueError(
-                f"parents must be (n_tasks, K), got {self.parents.shape}")
-        ids = np.arange(self.workload.n_tasks)[:, None]
-        if np.any(self.parents >= ids) or np.any(self.parents < -1):
-            raise ValueError("parents must satisfy -1 <= parents[i, k] < i "
-                             "(task ids are a topological order)")
+        self.src = np.asarray(self.src, np.int32).reshape(-1)
+        self.dst = np.asarray(self.dst, np.int32).reshape(-1)
+        self.nbytes = np.zeros(self.src.shape, np.float32) \
+            if self.nbytes is None else np.asarray(self.nbytes, np.float32)
+        n = self.workload.n_tasks
+        if not (self.src.shape == self.dst.shape == self.nbytes.shape):
+            raise ValueError("src, dst and nbytes must be (E,) each")
+        if np.any(self.src < 0) or np.any(self.dst >= n) \
+                or np.any(self.src >= self.dst):
+            raise ValueError("edges must satisfy 0 <= src[e] < dst[e] < "
+                             "n_tasks (task ids are a topological order)")
+        if np.any(np.diff(self.dst) < 0):
+            raise ValueError("edges must be sorted by dst")
         if np.any(np.diff(self.workload.arrival) < 0):
             raise ValueError("workflow arrivals must be nondecreasing in "
                              "task id (ids index the sorted workload)")
+
+    @classmethod
+    def from_parents(cls, workload: Workload, parents, nbytes=None,
+                     bandwidth: float | None = None) -> "Workflow":
+        """From a padded (N, K) parent table (``-1`` = no parent);
+        ``nbytes`` (optional) is aligned with it."""
+        parents = np.asarray(parents, np.int32)
+        if parents.ndim != 2 or parents.shape[0] != workload.n_tasks:
+            raise ValueError(
+                f"parents must be (n_tasks, K), got {parents.shape}")
+        dst, slot = np.nonzero(parents >= 0)
+        if np.any(parents[dst, slot] >= dst):
+            raise ValueError("parents must satisfy -1 <= parents[i, k] < i "
+                             "(task ids are a topological order)")
+        return cls(workload, parents[dst, slot], dst,
+                   None if nbytes is None
+                   else np.asarray(nbytes, np.float32)[dst, slot],
+                   bandwidth)
 
     @property
     def n_tasks(self) -> int:
@@ -329,64 +359,118 @@ class Workflow:
 
     @property
     def n_edges(self) -> int:
-        return int((self.parents >= 0).sum())
+        return int(self.src.shape[0])
+
+    @property
+    def in_degree(self) -> np.ndarray:
+        return np.bincount(self.dst, minlength=self.n_tasks)
+
+    @property
+    def delay(self) -> np.ndarray:
+        """(E,) f32 staging delay of each edge, in simulated time units."""
+        if self.bandwidth is None:
+            return np.zeros(self.src.shape, np.float32)
+        return (self.nbytes / np.float32(self.bandwidth)).astype(np.float32)
+
+    @property
+    def parents(self) -> np.ndarray:
+        """The padded (N, K) parent table (``-1`` pad), derived from the
+        edge list — the form the streaming window and the Gantt view
+        read."""
+        k = max(int(self.in_degree.max(initial=0)), 1)
+        out = np.full((self.n_tasks, k), -1, np.int32)
+        first = np.searchsorted(self.dst, self.dst, side="left")
+        out[self.dst, np.arange(self.n_edges) - first] = self.src
+        return out
+
+    def host_edges(self, width: int | None = None):
+        """The engine's ``state.Edges`` with numpy leaves, padded to
+        ``width`` edges (default: none) with edges in no task's range."""
+        from repro.core.state import Edges, pad_edges
+        first = np.searchsorted(self.dst, self.dst, side="left")
+        e = Edges(src=self.src, dst=self.dst, delay=self.delay,
+                  pos=(np.arange(self.n_edges) - first).astype(np.int32),
+                  end=np.searchsorted(self.dst, np.arange(self.n_tasks),
+                                      side="right").astype(np.int32))
+        return e if width is None else pad_edges(e, int(width))
 
     def ranks(self, mean_eet: np.ndarray | None = None) -> np.ndarray:
-        """(N,) HEFT upward ranks; ``mean_eet`` is the per-*type* mean
-        execution time across machine types (``eet.eet.mean(axis=1)``)."""
+        """(N,) HEFT upward ranks, staging delays included;
+        ``mean_eet`` is the per-*type* mean execution time across
+        machine types (``eet.eet.mean(axis=1)``)."""
         if mean_eet is None:
             w = np.ones(self.n_tasks, np.float64)
         else:
             w = np.asarray(mean_eet, np.float64)[self.workload.type_id]
-        return upward_ranks(self.parents, w)
+        return upward_ranks(self.src, self.dst, w, self.delay)
 
 
-def upward_ranks(parents: np.ndarray, w: np.ndarray) -> np.ndarray:
-    """HEFT upward rank: ``rank(i) = w[i] + max over children rank(c)``
-    (Topcuoglu et al. 2002), i.e. the expected length of the longest
-    path from a task to a DAG exit.  ``w`` is the per-task mean expected
-    execution time.  One reverse sweep over the topological id order:
-    when task ``j`` is visited its rank is final, so it relaxes each of
-    its parents.
+def upward_ranks(src, dst, w, delay=None) -> np.ndarray:
+    """HEFT upward rank: ``rank(i) = w[i] + max over child edges
+    (delay[e] + rank(c))`` (Topcuoglu et al. 2002, with the mean
+    communication cost c̄ of an edge its staging delay, which no machine
+    choice changes), i.e. the expected length of the longest path from a
+    task to a DAG exit.  ``w`` is the per-task mean expected execution
+    time.  One reverse sweep over the dst-sorted edges: when an edge is
+    visited its child's rank is final, so it relaxes the parent.
     """
-    parents = np.asarray(parents)
-    rank = np.asarray(w, np.float64).copy()
+    src, dst = np.asarray(src), np.asarray(dst)
     w = np.asarray(w, np.float64)
-    for j in range(parents.shape[0] - 1, -1, -1):
-        for p in parents[j]:
-            if p >= 0:
-                rank[p] = max(rank[p], w[p] + rank[j])
+    rank = w.copy()
+    d = np.zeros(src.shape, np.float64) if delay is None \
+        else np.asarray(delay, np.float64)
+    for e in range(src.shape[0] - 1, -1, -1):
+        p = src[e]
+        rank[p] = max(rank[p], w[p] + (d[e] + rank[dst[e]]))
     return rank.astype(np.float32)
+
+
+def _edges_of(parent_lists: list[list[int]]) -> tuple[np.ndarray,
+                                                      np.ndarray]:
+    """(src, dst) of per-task parent lists, dst-sorted, src-sorted
+    within a task."""
+    src = [p for ps in parent_lists for p in sorted(ps)]
+    dst = [i for i, ps in enumerate(parent_lists) for _ in ps]
+    return np.asarray(src, np.int32), np.asarray(dst, np.int32)
 
 
 def _assemble_workflow(parent_lists: list[list[int]], n_task_types: int,
                        mean_eet: np.ndarray | None, t0: float,
                        slack: float, slack_jitter: float,
-                       rng: np.random.Generator) -> Workflow:
-    """Common generator tail: types, path-aware deadlines, padded table.
+                       rng: np.random.Generator, *,
+                       type_id: np.ndarray | None = None,
+                       nbytes: np.ndarray | None = None,
+                       bandwidth: float | None = None) -> Workflow:
+    """Common generator tail: types, path-aware deadlines, edge list.
 
     Deadlines scale with each task's expected *critical-path length from
-    the sources* (``cum``), not its own EET alone — a slack factor that
-    ignored the chain depth would doom every deep task.
+    the sources* (``cum``, staging delays included), not its own EET
+    alone — a slack factor that ignored the chain depth would doom every
+    deep task.  ``type_id`` (optional) fixes the task types; otherwise
+    they are drawn uniformly.
     """
     n = len(parent_lists)
-    type_id = rng.integers(0, n_task_types, n)
+    if type_id is None:
+        type_id = rng.integers(0, n_task_types, n)
     me = np.ones(n_task_types, np.float32) if mean_eet is None \
         else np.asarray(mean_eet, np.float32)
     w = me[type_id].astype(np.float64)
+    src, dst = _edges_of(parent_lists)
+    wl = Workload(np.full(n, t0, np.float32), type_id,
+                  np.zeros(n, np.float32))
+    wf = Workflow(wl, src, dst, nbytes, bandwidth)
+    delay = wf.delay.astype(np.float64)
     cum = w.copy()
-    for i, ps in enumerate(parent_lists):
-        if ps:
-            cum[i] = w[i] + max(cum[p] for p in ps)
+    best = np.full(n, -np.inf)
+    for e in range(src.shape[0]):
+        i = dst[e]
+        best[i] = max(best[i], cum[src[e]] + delay[e])
+        if e + 1 == src.shape[0] or dst[e + 1] != i:
+            cum[i] = w[i] + best[i]
     jitter = rng.lognormal(0.0, slack_jitter, n) if slack_jitter > 0 \
         else np.ones(n)
-    deadline = (t0 + slack * jitter * cum).astype(np.float32)
-    k = max((len(ps) for ps in parent_lists), default=0) or 1
-    parents = np.full((n, k), -1, np.int32)
-    for i, ps in enumerate(parent_lists):
-        parents[i, :len(ps)] = sorted(ps)
-    wl = Workload(np.full(n, t0, np.float32), type_id, deadline)
-    return Workflow(wl, parents)
+    wl.deadline = (t0 + slack * jitter * cum).astype(np.float32)
+    return wf
 
 
 def chain_workflow(n_tasks: int, n_task_types: int = 1, *,
@@ -398,7 +482,6 @@ def chain_workflow(n_tasks: int, n_task_types: int = 1, *,
     parent_lists = [[] if i == 0 else [i - 1] for i in range(n_tasks)]
     return _assemble_workflow(parent_lists, n_task_types, mean_eet, t0,
                               slack, slack_jitter, rng)
-
 
 def fork_join_workflow(n_branches: int, branch_len: int = 1,
                        n_task_types: int = 1, *,
@@ -469,26 +552,226 @@ def layered_workflow(n_tasks: int, n_task_types: int = 1, *,
                               slack, slack_jitter, rng)
 
 
+# ---------------------------------------------------------------------------
+# Pegasus scientific workflows (Bharathi et al., "Characterization of
+# Scientific Workflows", WORKS 2008; Juve et al., "Characterizing and
+# profiling scientific workflows", FGCS 29(3), 2013)
+# ---------------------------------------------------------------------------
+@dataclass(frozen=True)
+class JobProfile:
+    """The job types of a Pegasus workflow: a task's type is its job type.
+
+    ``runtime_s`` is each job type's mean runtime (seconds) and
+    ``output_mb`` the size of the file a job of the type hands each of
+    its children.  One simulated time unit is the workflow's mean job
+    runtime at its task counts (``counts(n)``), so a fail rate or an MTTR
+    means for a workflow what it means for Braun's tasks.  The numbers
+    are ``bench/configs/pegasus1000x16.json``'s ``assumed`` ones.
+    """
+    jobs: tuple[str, ...]
+    runtime_s: tuple[float, ...]
+    output_mb: tuple[float, ...]
+    counts: object          # n_tasks -> (J,) tasks of each job type
+
+    def seconds_per_unit(self, n_tasks: int) -> float:
+        c = np.asarray(self.counts(n_tasks), np.float64)
+        return float(c @ np.asarray(self.runtime_s, np.float64) / c.sum())
+
+    def costs(self, n_tasks: int) -> np.ndarray:
+        """(J,) mean runtime of each job type in simulated time units."""
+        return np.asarray(self.runtime_s, np.float64) / \
+            self.seconds_per_unit(n_tasks)
+
+
+def montage_counts(n: int) -> tuple[int, int]:
+    """(P mProjectPP, D mDiffFit) with 2P + D + 6 = n and D ≈ 3P
+    (overlapping pairs; at least one, at most P(P-1)/2)."""
+    p = max(2, round((n - 6) / 5))
+    while n - 6 - 2 * p > p * (p - 1) // 2:
+        p += 1
+    if n - 6 - 2 * p < 1:
+        raise ValueError(f"montage needs at least 11 tasks, got {n}")
+    return p, n - 6 - 2 * p
+
+
+def cybershake_counts(n: int) -> tuple[int, int]:
+    """(S ExtractSGT, K SeismogramSynthesis) with S + 2K + 2 = n."""
+    s = max(2, round(n / 250))
+    s += (n - 2 - s) % 2
+    if n - 2 - s < 2:
+        raise ValueError(f"cybershake needs at least 6 tasks, got {n}")
+    return s, (n - 2 - s) // 2
+
+
+def epigenomics_counts(n: int) -> tuple[int, int, int]:
+    """(L lanes, C whole 4-stage chunks, r stages of a last partial
+    chunk) with 2L + 4C + r + 2 = n; L odd, about n / 250."""
+    lanes = max(1, n // 250) | 1
+    if n - 2 - 2 * lanes < 4:
+        raise ValueError(f"epigenomics needs at least 8 tasks, got {n}")
+    c, r = divmod(n - 2 - 2 * lanes, 4)
+    return lanes, c, r
+
+
+def _montage_type_counts(n):
+    p, d = montage_counts(n)
+    return [p, d, 1, 1, p, 1, 1, 1, 1]
+
+
+def _cybershake_type_counts(n):
+    s, k = cybershake_counts(n)
+    return [s, k, k, 1, 1]
+
+
+def _epigenomics_type_counts(n):
+    lanes, c, r = epigenomics_counts(n)
+    return [lanes] + [c + (r > j) for j in range(4)] + [lanes, 1, 1]
+
+
+JOB_PROFILES: dict[str, JobProfile] = {
+    "montage": JobProfile(
+        ("mProjectPP", "mDiffFit", "mConcatFit", "mBgModel", "mBackground",
+         "mImgtbl", "mAdd", "mShrink", "mJPEG"),
+        (1.73, 0.66, 143.26, 384.49, 1.72, 2.78, 282.37, 66.10, 0.64),
+        (8.0, 0.01, 0.1, 0.1, 8.0, 0.01, 170.0, 1.0, 0.3),
+        _montage_type_counts),
+    "cybershake": JobProfile(
+        ("ExtractSGT", "SeismogramSynthesis", "PeakValCalcOkaya",
+         "ZipSeis", "ZipPSA"),
+        (110.58, 79.47, 0.55, 265.73, 195.80),
+        (150.0, 0.02, 0.001, 10.0, 0.5),
+        _cybershake_type_counts),
+    "epigenomics": JobProfile(
+        ("fastQSplit", "filterContams", "sol2sanger", "fastq2bfq", "map",
+         "mapMerge", "maqIndex", "pileup"),
+        (34.34, 2.47, 0.48, 1.40, 201.78, 11.00, 43.57, 55.95),
+        (10.0, 10.0, 10.0, 3.0, 1.5, 30.0, 100.0, 80.0),
+        _epigenomics_type_counts),
+}
+
+
+def _montage_dag(n: int, rng) -> tuple[list[int], list[list[int]]]:
+    """mProjectPP x P -> mDiffFit x D (two overlapping projections
+    each) -> mConcatFit -> mBgModel -> mBackground x P (mBgModel and
+    its own projection) -> mImgtbl -> mAdd -> mShrink -> mJPEG.  The D
+    pairs are drawn from the images' nearest neighbours in id order."""
+    p, d = montage_counts(n)
+    cand = []
+    k = 1
+    while len(cand) < d:
+        cand += [(i, i + k) for i in range(p - k)]
+        k += 1
+    pairs = [cand[j] for j in np.sort(rng.choice(len(cand), d,
+                                                 replace=False))]
+    diff0, concat = p, p + d
+    bg0 = p + d + 2
+    imgtbl = bg0 + p
+    types = [0] * p + [1] * d + [2, 3] + [4] * p + [5, 6, 7, 8]
+    parents = [[] for _ in range(p)] + [list(ab) for ab in pairs]
+    parents += [list(range(diff0, diff0 + d)), [concat]]
+    parents += [[i, concat + 1] for i in range(p)]
+    parents += [list(range(bg0, bg0 + p)), [imgtbl], [imgtbl + 1],
+                [imgtbl + 2]]
+    return types, parents
+
+
+def _cybershake_dag(n: int, rng) -> tuple[list[int], list[list[int]]]:
+    """ExtractSGT x S -> SeismogramSynthesis x K (one extraction each,
+    drawn) -> PeakValCalcOkaya x K (its own seismogram); ZipSeis over
+    every seismogram, ZipPSA over every peak value."""
+    s, k = cybershake_counts(n)
+    sgt = np.sort(rng.integers(0, s, k))
+    types = [0] * s + [1] * k + [2] * k + [3, 4]
+    parents = [[] for _ in range(s)] + [[int(g)] for g in sgt]
+    parents += [[s + j] for j in range(k)]
+    parents += [list(range(s, s + k)), list(range(s + k, s + 2 * k))]
+    return types, parents
+
+
+def _epigenomics_dag(n: int, rng) -> tuple[list[int], list[list[int]]]:
+    """fastQSplit x L -> per chunk the chain filterContams -> sol2sanger
+    -> fastq2bfq -> map -> mapMerge of its lane; maqIndex over every
+    mapMerge, then pileup.  Chunks go to lanes round-robin; a last
+    partial chunk (r < 4 stages, only where n - 2 - 2L is not a
+    multiple of 4) feeds its lane's mapMerge from its last stage."""
+    lanes, c, r = epigenomics_counts(n)
+    types = [0] * lanes
+    parents: list[list[int]] = [[] for _ in range(lanes)]
+    merge_in: list[list[int]] = [[] for _ in range(lanes)]
+    for j, stages in enumerate([4] * c + ([r] if r else [])):
+        lane = j % lanes
+        prev = lane
+        for st in range(stages):
+            types.append(1 + st)
+            parents.append([prev])
+            prev = len(parents) - 1
+        merge_in[lane].append(prev)
+    merge0 = len(parents)
+    types += [5] * lanes + [6, 7]
+    parents += merge_in + [list(range(merge0, merge0 + lanes)),
+                           [merge0 + lanes]]
+    return types, parents
+
+
+_PEGASUS_DAGS = {"montage": _montage_dag, "cybershake": _cybershake_dag,
+                 "epigenomics": _epigenomics_dag}
+
+
+def pegasus_workflow(shape: str, n_tasks: int, n_task_types: int, *,
+                     mean_eet: np.ndarray | None = None,
+                     bandwidth: float | None = None, t0: float = 0.0,
+                     slack: float = 4.0, slack_jitter: float = 0.0,
+                     seed: int = 0) -> Workflow:
+    """One Pegasus workflow of exactly ``n_tasks`` tasks (``montage``,
+    ``cybershake`` or ``epigenomics``), each task's type its job type
+    (``JOB_PROFILES[shape].jobs``), each edge the parent's output file.
+    ``bandwidth`` is the shared storage's, in MB/s (None = no staging
+    cost); it becomes MB per simulated time unit through the profile's
+    ``seconds_per_unit``."""
+    prof = JOB_PROFILES[shape]
+    if n_task_types < len(prof.jobs):
+        raise ValueError(f"{shape} has {len(prof.jobs)} job types, "
+                         f"n_task_types is {n_task_types}")
+    rng = np.random.default_rng(seed)
+    types, parent_lists = _PEGASUS_DAGS[shape](n_tasks, rng)
+    type_id = np.asarray(types, np.int32)
+    src, _ = _edges_of(parent_lists)
+    out_mb = np.asarray(prof.output_mb, np.float32)
+    bw = None if bandwidth is None else \
+        float(bandwidth) * prof.seconds_per_unit(n_tasks)
+    return _assemble_workflow(parent_lists, n_task_types, mean_eet, t0,
+                              slack, slack_jitter, rng, type_id=type_id,
+                              nbytes=out_mb[type_id[src]], bandwidth=bw)
+
+
 # Named DAG shapes with a common call shape, so experiment specs can
 # treat "workflow shape" as a grid axis (launch/experiment.py):
-# f(n_tasks, n_task_types, mean_eet, seed) -> Workflow
+# f(n_tasks, n_task_types, mean_eet, seed, bandwidth=None) -> Workflow
+# (``bandwidth``: the shared storage's MB/s, which only shapes whose
+# edges carry files take).  A shape in ``JOB_PROFILES`` types its tasks
+# by job, and its EET rows follow the job runtimes.
 WORKFLOW_GENERATORS = {
-    "chain": lambda n, ntt, me, seed: chain_workflow(
+    "chain": lambda n, ntt, me, seed, bandwidth=None: chain_workflow(
         n, ntt, mean_eet=me, seed=seed),
-    "fork_join": lambda n, ntt, me, seed: fork_join_workflow(
+    "fork_join": lambda n, ntt, me, seed, bandwidth=None: fork_join_workflow(
         max(n - 2, 1), 1, ntt, mean_eet=me, seed=seed),
-    "map_reduce": lambda n, ntt, me, seed: map_reduce_workflow(
-        max(n - max(n // 4, 1), 1), max(n // 4, 1), ntt, mean_eet=me,
-        seed=seed),
-    "layered": lambda n, ntt, me, seed: layered_workflow(
+    "map_reduce": lambda n, ntt, me, seed, bandwidth=None:
+        map_reduce_workflow(max(n - max(n // 4, 1), 1), max(n // 4, 1),
+                            ntt, mean_eet=me, seed=seed),
+    "layered": lambda n, ntt, me, seed, bandwidth=None: layered_workflow(
         n, ntt, n_layers=4, mean_eet=me, seed=seed),
+    **{name: (lambda n, ntt, me, seed, bandwidth=None, _s=name:
+              pegasus_workflow(_s, n, ntt, mean_eet=me, bandwidth=bandwidth,
+                               seed=seed))
+       for name in JOB_PROFILES},
 }
 
 
 def register_workflow_generator(name: str, fn) -> None:
     """Register a custom DAG shape as a sweep axis value.
 
-    ``fn(n_tasks, n_task_types, mean_eet, seed) -> Workflow``.
+    ``fn(n_tasks, n_task_types, mean_eet, seed) -> Workflow``; a shape
+    whose edges carry files also takes ``bandwidth=`` (MB/s).
     Registered names are immediately valid in
     ``experiment.WorkloadAxis(shapes=...)``; duplicates raise."""
     if name in WORKFLOW_GENERATORS:

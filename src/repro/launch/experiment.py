@@ -52,8 +52,9 @@ from repro.core import schedulers as P
 from repro.core import state as S
 from repro.core import telemetry as TL
 from repro.core.eet import synth_eet
-from repro.core.workload import (WORKFLOW_GENERATORS, make_scenario,
-                                 resolve_arrivals, resolve_shapes)
+from repro.core.workload import (JOB_PROFILES, WORKFLOW_GENERATORS,
+                                 make_scenario, resolve_arrivals,
+                                 resolve_shapes)
 
 __all__ = [
     "FleetAxis", "WorkloadAxis", "ScenarioAxis", "PolicyAxis",
@@ -144,9 +145,11 @@ class WorkloadAxis:
     the arrival process a grid axis (None = Poisson everywhere, which
     preserves the exact draws of the legacy builders).  ``shapes`` names
     ``workload.WORKFLOW_GENERATORS`` entries and switches the experiment
-    to workflow mode (parent tables padded to the grid's widest
-    in-degree, HEFT ranks precomputed, policy axis *paired* per DAG
-    instance).  The two are mutually exclusive.
+    to workflow mode (edge lists padded to the grid's widest edge count,
+    HEFT ranks precomputed, policy axis *paired* per DAG instance).  The
+    two are mutually exclusive.  ``bandwidth`` (workflow mode, MB/s) is
+    the shared storage's: each edge's file is staged at it before the
+    child may run (None = no staging cost).
 
     ``streaming=W`` runs every replica through the bounded-memory
     streaming engine (``core/streaming.py``) with a W-slot live-task
@@ -155,9 +158,9 @@ class WorkloadAxis:
     sets the arrival-chunk granularity (results are invariant to it;
     default ``min(n_tasks, W)``).  Streaming composes with ``arrivals``
     and scenario axes but not with ``shapes`` (experiment-level DAG
-    cells pad parent tables across the grid, which has no bounded-window
+    cells pad edge lists across the grid, which has no bounded-window
     equivalent yet — use ``streaming.simulate_stream`` directly for a
-    single DAG; docs/streaming.md).
+    single DAG without staging delays; docs/streaming.md).
     """
     n_tasks: int
     n_task_types: int = 4
@@ -166,6 +169,7 @@ class WorkloadAxis:
     shapes: tuple[str, ...] | None = None
     streaming: int | None = None
     stream_chunk: int | None = None
+    bandwidth: float | None = None
 
     def __post_init__(self):
         object.__setattr__(self, "arrivals", _astuple(self.arrivals))
@@ -178,11 +182,18 @@ class WorkloadAxis:
             resolve_arrivals(self.arrivals)
         if self.shapes is not None:
             resolve_shapes(self.shapes)
+        if self.bandwidth is not None:
+            if self.shapes is None:
+                raise ValueError("bandwidth requires shapes (workflow "
+                                 "edges are what is staged)")
+            if not self.bandwidth > 0:
+                raise ValueError(f"bandwidth must be > 0, got "
+                                 f"{self.bandwidth}")
         if self.streaming is not None:
             if self.shapes is not None:
                 raise ValueError(
                     "streaming does not compose with shapes (workflow "
-                    "cells pad parent tables across the grid); run DAGs "
+                    "cells pad edge lists across the grid); run DAGs "
                     "through streaming.simulate_stream directly")
             if self.streaming < 1:
                 raise ValueError(f"streaming window must be >= 1, got "
@@ -333,6 +344,7 @@ class Replicas(NamedTuple):
     (``jax.device_put``).
 
     ``dynamics`` / ``parents`` are None when the spec compiles them out;
+    ``parents`` is a workflow grid's ``state.Edges`` edge lists;
     ``legacy()`` returns the positional tuple shape the pre-spec
     builders produced (4-, 5- or 6-tuple)."""
     tasks: S.TaskTable
@@ -460,11 +472,10 @@ def _draw_workflow_cell(spec: ExperimentSpec, cell: int):
     n_s, n_f = len(shapes), len(sc.fail_rates)
     crng = np.random.default_rng(spec.seed + 104729 * cell)
     eet = synth_eet(wk.n_task_types, fl.n_machine_types,
-                    inconsistency=0.3, seed=spec.seed + cell)
+                    inconsistency=0.3, seed=spec.seed + cell,
+                    costs=_job_costs(spec, shapes[cell % n_s]))
     power = _draw_power(crng, fl.n_machine_types)
-    gen = WORKFLOW_GENERATORS[shapes[cell % n_s]]
-    wf = gen(wk.n_tasks, wk.n_task_types, eet.eet.mean(1),
-             spec.seed + 7919 * cell)
+    wf = _generate(spec, cell, eet.eet.mean(1))
     scen = make_scenario(
         wf.workload, fl.n_machines,
         fail_rate=sc.fail_rates[(cell // n_s) % n_f],
@@ -477,53 +488,74 @@ def _draw_workflow_cell(spec: ExperimentSpec, cell: int):
     mt = crng.integers(0, fl.n_machine_types, fl.n_machines)
     tab = E.make_host_tables(eet, power, wk.n_tasks, noise=noise,
                              rank=wf.ranks(eet.eet.mean(1)))
-    return tt, mt, tab, scen.host_dynamics(), wf.parents
+    return tt, mt, tab, scen.host_dynamics(), wf.host_edges()
 
 
-_KMAX_CACHE: dict[ExperimentSpec, int] = {}
+def _job_costs(spec: ExperimentSpec, shape: str) -> np.ndarray | None:
+    """(T,) EET cost of each task type for a shape whose task types are
+    job types (``workload.JOB_PROFILES``): the job runtimes in simulated
+    time units, 1.0 for the types the shape leaves unused; else None
+    (costs drawn)."""
+    prof = JOB_PROFILES.get(shape)
+    if prof is None:
+        return None
+    wk = spec.workload
+    if wk.n_task_types < len(prof.jobs):
+        raise ValueError(f"shape {shape!r} has {len(prof.jobs)} job types, "
+                         f"n_task_types is {wk.n_task_types}")
+    costs = np.ones(wk.n_task_types, np.float64)
+    costs[:len(prof.jobs)] = prof.costs(wk.n_tasks)
+    return costs
 
 
-def _workflow_kmax(spec: ExperimentSpec) -> int:
-    """Grid-wide widest DAG in-degree — the parent-table pad width.
+def _generate(spec: ExperimentSpec, cell: int, mean_eet):
+    """Cell ``cell``'s DAG (its structure does not depend on
+    ``mean_eet``, which only sets the deadlines)."""
+    wk = spec.workload
+    gen = WORKFLOW_GENERATORS[wk.shapes[cell % len(wk.shapes)]]
+    kw = {} if wk.bandwidth is None else {"bandwidth": wk.bandwidth}
+    return gen(wk.n_tasks, wk.n_task_types, mean_eet,
+               spec.seed + 7919 * cell, **kw)
+
+
+_EMAX_CACHE: dict[ExperimentSpec, int] = {}
+
+
+def _workflow_emax(spec: ExperimentSpec) -> int:
+    """Grid-wide widest DAG edge count — the edge-list pad width.
 
     Chunked normalization needs it up front (a chunk only sees its own
     cells, but every chunk must pad to the same width as the monolithic
-    grid).  DAG generation is deterministic per cell, so a cheap
-    generate-and-discard pre-pass over the cells recovers exactly the
-    width :func:`_materialize_workflow` computes from the full grid."""
-    km = _KMAX_CACHE.get(spec)
-    if km is None:
-        wk, fl = spec.workload, spec.fleet
-        shapes = wk.shapes
-        n_s = len(shapes)
+    grid).  DAG structure is deterministic per cell and independent of
+    the EET, so a generate-and-discard pre-pass over the cells recovers
+    exactly the width :func:`_materialize_workflow` computes from the
+    full grid."""
+    em = _EMAX_CACHE.get(spec)
+    if em is None:
         n_p = len(spec.policy.policies)
-        km = 0
-        for cell in range(-(-spec.n_replicas // n_p)):
-            eet = synth_eet(wk.n_task_types, fl.n_machine_types,
-                            inconsistency=0.3, seed=spec.seed + cell)
-            gen = WORKFLOW_GENERATORS[shapes[cell % n_s]]
-            wf = gen(wk.n_tasks, wk.n_task_types, eet.eet.mean(1),
-                     spec.seed + 7919 * cell)
-            km = max(km, wf.parents.shape[1])
-        _KMAX_CACHE[spec] = km
-    return km
+        em = max([1] + [_generate(spec, cell, None).n_edges
+                        for cell in range(-(-spec.n_replicas // n_p))])
+        _EMAX_CACHE[spec] = em
+    return em
 
 
 def _materialize_workflow(spec: ExperimentSpec, lo: int = 0,
                           hi: int | None = None,
-                          k_max: int | None = None) -> Replicas:
+                          e_max: int | None = None) -> Replicas:
     """Workflow mode: per-cell RNG, *paired* policy axis — the ``n_p``
     consecutive replicas of a cell share one DAG / EET / fleet / failure
-    trace.  Parent tables pad to the grid's widest in-degree (``k_max``,
+    trace.  Edge lists pad to the grid's widest edge count (``e_max``,
     computed from the materialized range when not given — chunked
-    callers pass the grid-wide :func:`_workflow_kmax`)."""
+    callers pass the grid-wide :func:`_workflow_emax`); no (N, K)
+    parent table is built.  The ``draw`` span records the widest edge
+    count and in-degree drawn (``n_edges``, ``max_in_degree``)."""
     hi = spec.n_replicas if hi is None else hi
     policies = spec.policy.policies
     n_p = len(policies)
-    tts, mts, tabs, pids, dyns, pars = [], [], [], [], [], []
-    with TL.span("draw"):
+    tts, mts, tabs, pids, dyns, edges = [], [], [], [], [], []
+    with TL.span("draw") as dsp:
         for cell in range(lo // n_p, -(-hi // n_p)):
-            tt, mt, tab, dyn, parents = _draw_workflow_cell(spec, cell)
+            tt, mt, tab, dyn, e = _draw_workflow_cell(spec, cell)
             for p in range(n_p):
                 r = cell * n_p + p
                 if lo <= r < hi:
@@ -532,24 +564,24 @@ def _materialize_workflow(spec: ExperimentSpec, lo: int = 0,
                     tabs.append(tab)
                     pids.append(P.POLICY_IDS[policies[p]])
                     dyns.append(dyn)
-                    pars.append(parents)
+                    edges.append(e)
+        dsp.update(n_edges=max(e.src.shape[0] for e in edges),
+                   max_in_degree=max(int(np.diff(e.end, prepend=0).max())
+                                     for e in edges))
     with TL.span("stack"):
-        k_max = max(p.shape[1] for p in pars) if k_max is None else k_max
-        parents = np.full((hi - lo, spec.workload.n_tasks, k_max), -1,
-                          np.int32)
-        for i, p in enumerate(pars):
-            parents[i, :, :p.shape[1]] = p
+        if e_max is None:
+            e_max = max([1] + [e.src.shape[0] for e in edges])
         reps = Replicas(
             _stack(tts), np.stack(mts).astype(np.int32),
             _stack(tabs), np.asarray(pids, np.int32), _stack(dyns),
-            parents)
+            _stack([S.pad_edges(e, e_max) for e in edges]))
     return reps
 
 
 def normalize(spec: ExperimentSpec) -> Replicas:
     """Materialize the spec's grid into one stacked :class:`Replicas`
-    pytree — the normalization pass of the pipeline (padding parent
-    tables, pairing policy grids, materializing dynamics traces).
+    pytree — the normalization pass of the pipeline (padding edge
+    lists, pairing policy grids, materializing dynamics traces).
 
     Every leaf is a numpy array built on the host: normalize starts no
     device work, so it can run beside a computation on the device, and
@@ -572,7 +604,7 @@ def normalize_chunk(spec: ExperimentSpec, lo: int, hi: int) -> Replicas:
                          f"[0, {spec.n_replicas})")
     if spec.workflow:
         return _materialize_workflow(spec, lo, hi,
-                                     k_max=_workflow_kmax(spec))
+                                     e_max=_workflow_emax(spec))
     return _materialize_flat(spec, lo, hi)
 
 

@@ -304,8 +304,8 @@ def make_workflow_replicas(n_replicas: int, n_tasks: int, n_machines: int,
                            spot_frac: float = 0.0, mttr: float = 4.0,
                            n_intervals: int = 4, seed: int = 0) -> tuple:
     """DEPRECATED shim -> ``experiment.normalize`` in workflow mode
-    (policy axis *paired* per DAG instance; parent tables padded to the
-    grid's widest in-degree; HEFT ranks precomputed).
+    (policy axis *paired* per DAG instance; edge lists padded to the
+    grid's widest edge count; HEFT ranks precomputed).
 
     Returns ``(task_tables, mtypes, tables, policy_ids, dynamics,
     parents)`` — bitwise-identical to the pre-spec builder.

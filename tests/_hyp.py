@@ -2,16 +2,16 @@
 
 `hypothesis` is a dev extra (see pyproject.toml), not a runtime dep.  When
 it is installed, this module re-exports the real ``given``/``settings``/
-``strategies``.  When it is not, property-based tests are collected as
-skips (instead of the whole module failing to import) and every plain
-test in the same file still runs.
+``example``/``strategies``.  When it is not, property-based tests are
+collected as skips (instead of the whole module failing to import) and
+every plain test in the same file still runs.
 """
 from __future__ import annotations
 
 import pytest
 
 try:
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:
@@ -32,6 +32,8 @@ except ImportError:
             return fn
         return deco
 
+    example = settings
+
     class _AnyStrategy:
         """Stand-in for ``hypothesis.strategies``: every attribute is a
         callable returning None (only ever passed to the stub ``given``)."""
@@ -41,4 +43,4 @@ except ImportError:
 
     st = _AnyStrategy()
 
-__all__ = ["given", "settings", "st", "HAVE_HYPOTHESIS"]
+__all__ = ["example", "given", "settings", "st", "HAVE_HYPOTHESIS"]
