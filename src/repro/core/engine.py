@@ -198,12 +198,20 @@ def _availability(st: S.SimState, tb: S.StaticTables,
     n_pre = st.n_preempts.at[jnp.where(hit, running0, n)].add(1, mode="drop")
 
     # -- queued tasks on down machines: flush the machine queue -----------
+    # Each task's (down, kill) bits are its machine's, read by a one-hot
+    # compare over the M machines rather than gathered per task: a batched
+    # per-task gather is the TPU's slowest op here (docs/engine_perf.md).
     m_of = jnp.clip(machine, 0, n_m - 1)
-    in_down_q = (status == S.IN_MQ) & (machine >= 0) & down[m_of]
-    kq = in_down_q & dyn.kill[m_of]
-    rq = in_down_q & ~dyn.kill[m_of]
+    code = down.astype(jnp.int32) | (dyn.kill.astype(jnp.int32) << 1)
+    onehot = m_of[None, :] == jnp.arange(n_m, dtype=m_of.dtype)[:, None]
+    code_of = jnp.max(jnp.where(onehot, code[:, None], 0), axis=0)  # (N,)
+    kill_of = code_of >= 2
+    in_down_q = (status == S.IN_MQ) & (machine >= 0) & \
+        ((code_of & 1) == 1)
+    kq = in_down_q & kill_of
+    rq = in_down_q & ~kill_of
     if st.trace is not None:
-        kinds = jnp.where(dyn.kill[m_of], T.EV_PREEMPT, T.EV_REQUEUE)
+        kinds = jnp.where(kill_of, T.EV_PREEMPT, T.EV_REQUEUE)
         st = replace(st, trace=T.record(
             st.trace, st.time, kinds, jnp.arange(n), machine, in_down_q))
     status = jnp.where(kq, S.PREEMPTED, status)
