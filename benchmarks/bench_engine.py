@@ -23,12 +23,7 @@ the vectorized engine makes *simulated* studies cheap at scale:
   T11. the chunked Monte-Carlo driver (launch/chunked.py) scales flat:
       per-replica cost at R=100k stays within 1.3x of R=1k (donated
       buffers + device-side SweepAgg reduction keep host and device
-      memory O(chunk)) (docs/scaling.md);
-  T12. the overhauled drain hot loop (carried machine-available vector,
-      incremental queue counters, zero-trip empty drains) schedules a
-      dense N=512 batch instance >= 1.5x faster per replica than the
-      PR-9 baseline loop (``SimParams(legacy_drain=True)``), bitwise
-      the same schedule (docs/engine_perf.md).
+      memory O(chunk)) (docs/scaling.md).
 
 All rows run through the declarative spec pipeline (one cached
 executable per SimParams) — the same path users take.
@@ -252,71 +247,6 @@ def time_chunked_sweep(n_small: int, n_big: int, chunk: int = 250):
     return per[0], per[1], stats
 
 
-def _dense_batch_inputs(n_replicas: int, n_tasks: int, n_machines: int,
-                        policy: str = "mct", seed: int = 0):
-    """E2C batch-mode instance: every task arrives at t=0, so the first
-    event's drain schedules the whole queue in one deep pass."""
-    tt, mt, tb, pid = make_replicas(n_replicas, n_tasks, n_machines,
-                                    policies=[policy], seed=seed)
-    fields = {f: getattr(tt, f) for f in tt.__dataclass_fields__}
-    fields["arrival"] = jnp.zeros_like(tt.arrival)
-    return type(tt)(**fields), mt, tb, pid
-
-
-def time_hot_loop(n_tasks: int, n_machines: int = N_MACHINES,
-                  lcap: int | None = None, n_replicas: int = 4,
-                  reps: int = 10) -> dict:
-    """T12: the overhauled drain hot loop vs the PR-9 baseline.
-
-    Isolates the scheduler drain on a dense batch instance (all N tasks
-    in the batch queue at t=0; ``lcap`` sized so one drain schedules
-    everything) — per replica the loop runs N dispatch->apply trips,
-    the path the hot-loop overhaul rewrote.  Three configs, identical
-    decisions (bitwise — tests/test_drain_kway.py):
-
-    * ``legacy_drain=True`` — the PR-9 loop: O(N*M) machine_available
-      rebuild inside every dispatch plus the O(N) status-scan bound;
-    * ``drain_k=1`` — the default hot path: machine-available carried
-      through the loop (one O(M) update per decision), bound from the
-      incremental ``n_batch`` counter, empty queues drain in zero trips;
-    * ``drain_k=8`` — the K-way speculative width, measured for the
-      record: on a CPU host it trades a few large-tensor ops per
-      decision for many small ones and loses (docs/engine_perf.md).
-
-    Returns per-replica seconds per config.  Policy id is a
-    compile-time constant (grouped-dispatch analog), so the switch
-    compiles to the single mct branch.
-    """
-    from repro.core import state as S
-    if lcap is None:
-        lcap = max(4, -(-n_tasks // n_machines))
-    tt, mt, tb, _ = _dense_batch_inputs(n_replicas, n_tasks, n_machines)
-    pid_const = jnp.int32(P.POLICY_IDS["mct"])
-
-    def harness(params):
-        def one(tasks, mtype, table):
-            st = S.init_state(tasks, mtype, None, None)
-            st = E._arrivals(st, params.qcap)
-            st = E._drain(st, table, pid_const, params)
-            return st.tasks.status, st.machines.busy_until
-        return jax.jit(jax.vmap(one))
-
-    out = {}
-    for label, params in (
-            ("legacy", E.SimParams(lcap=lcap, legacy_drain=True)),
-            ("hot", E.SimParams(lcap=lcap, drain_k=1)),
-            ("spec_k8", E.SimParams(lcap=lcap, drain_k=8))):
-        fn = harness(params)
-        res = fn(tt, mt, tb)
-        jax.block_until_ready(res)
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            res = fn(tt, mt, tb)
-        jax.block_until_ready(res)
-        out[label] = (time.perf_counter() - t0) / reps / n_replicas
-    return out
-
-
 def run(out_dir=None, smoke: bool = False) -> dict:
     # ref engine indexes tuple fields positionally; rebuild host-side
     inputs = make_replicas(2, N_TASKS, N_MACHINES, seed=0)
@@ -446,19 +376,6 @@ def run(out_dir=None, smoke: bool = False) -> dict:
                      "per_replica_ms": round(per * 1e3, 3),
                      "replicas_per_s": round(1 / per, 1)})
 
-    # drain hot loop vs the PR-9 baseline on a dense batch instance (T12)
-    hot_n = 256 if smoke else 512
-    hot = time_hot_loop(hot_n)
-    for label in ("legacy", "hot", "spec_k8"):
-        per = hot[label]
-        rows.append({"replicas": f"{hot_n} tasks (drain {label}, dense)",
-                     "total_s": round(per * 4, 4),
-                     "per_replica_ms": round(per * 1e3, 3),
-                     "replicas_per_s": round(1 / per, 1)})
-
-    hot_loop = {k: round(v * 1e3, 3) for k, v in hot.items()}
-    hot_loop["speedup_vs_legacy"] = round(hot["legacy"] / hot["hot"], 2)
-
     checks = {
         "T1_jit_beats_python_ref": bool(per_replica_1 < ref_per_replica),
         "T2_vmap_amortizes": bool(per_replica_big
@@ -480,10 +397,8 @@ def run(out_dir=None, smoke: bool = False) -> dict:
             metrics_per * 1e3 < 2 * static_same_n),
         "T11_chunked_per_replica_flat": bool(
             chunked_big < 1.3 * chunked_small),
-        "T12_hot_loop_speedup": bool(hot["legacy"] >= 1.5 * hot["hot"]),
     }
     payload = {"rows": rows,
-               "hot_loop": hot_loop,
                "chunked": {
                    "chunk": 250,
                    "n_small": chunk_small,

@@ -99,20 +99,6 @@ class SimParams(NamedTuple):
     #                               the identical uninstrumented HLO
     metrics_spec: ME.MetricsSpec | None = None   # bucket/window geometry;
     #                               None = metrics.DEFAULT_SPEC
-    drain_k: int = 1              # speculative drain width: candidate
-    #                               decisions per drain trip, validated to
-    #                               a sequentially-consistent prefix and
-    #                               applied in one masked scatter — bitwise
-    #                               the single-step schedule
-    #                               (docs/engine_perf.md); 1 = sequential.
-    #                               Pays off when dispatch is cheap
-    #                               (grouped single-policy runs); under the
-    #                               batched lax.switch every branch runs
-    #                               K-fold, so the sweep default stays 1
-    legacy_drain: bool = False    # PR-9-equivalent drain loop (recompute
-    #                               machine_available + O(N) queue scan
-    #                               every iteration) — the measured T12
-    #                               baseline, never a production setting
 
 
 # --------------------------------------------------------------------------
@@ -386,54 +372,6 @@ def _apply_decision(st: S.SimState, dec: P.Decision) -> S.SimState:
                    n_live=st.n_live - do_cancel.astype(jnp.int32))
 
 
-def _apply_decisions_k(st: S.SimState, dec: P.Decision, use: jnp.ndarray
-                       ) -> tuple[S.SimState, jnp.ndarray]:
-    """Apply a validated K-prefix of drain decisions in one masked scatter.
-
-    ``use`` masks the sequentially-consistent prefix (``P.dispatch_k``,
-    which also returns the carried machine-available vector after the
-    prefix); per-candidate semantics are exactly ``_apply_decision``'s,
-    with the mapping-sequence numbers assigned in candidate order
-    (exclusive cumsum) and ``rr_ptr`` advanced past the last applied
-    map.  Returns the state and the applied count for the drain-loop
-    bound.
-    """
-    tasks = st.tasks
-    n = tasks.arrival.shape[0]
-    n_m = st.machines.mtype.shape[0]
-    k = dec.task.shape[0]
-    do_map = use & ~dec.cancel
-    do_cxl = use & dec.cancel
-    tid_map = jnp.where(do_map, dec.task, n)
-    tid_cxl = jnp.where(do_cxl, dec.task, n)
-    seq_rank = jnp.cumsum(do_map.astype(jnp.int32)) - \
-        do_map.astype(jnp.int32)
-    tasks = replace(
-        tasks,
-        status=tasks.status.at[tid_map].set(S.IN_MQ, mode="drop")
-                           .at[tid_cxl].set(S.CANCELLED, mode="drop"),
-        machine=tasks.machine.at[tid_map].set(dec.machine, mode="drop"),
-        seq=tasks.seq.at[tid_map].set(st.seq_counter + seq_rank,
-                                      mode="drop"),
-        t_end=tasks.t_end.at[tid_cxl].set(st.time, mode="drop"),
-    )
-    mid = jnp.where(do_map, dec.machine, n_m)
-    mq_count = st.mq_count.at[mid].add(1, mode="drop")
-    # rr_ptr: one past the last applied mapped machine (unchanged when the
-    # prefix mapped nothing) — sequential per-map advancement telescopes
-    last = jnp.max(jnp.where(do_map, jnp.arange(k), -1))
-    m_last = dec.machine[jnp.clip(last, 0, k - 1)]
-    rr_ptr = jnp.where(last >= 0, (m_last + 1) % n_m, st.rr_ptr)
-    n_applied = jnp.sum(use, dtype=jnp.int32)
-    st = replace(st, tasks=tasks,
-                 seq_counter=st.seq_counter + jnp.sum(do_map,
-                                                      dtype=jnp.int32),
-                 rr_ptr=rr_ptr, mq_count=mq_count,
-                 n_batch=st.n_batch - n_applied,
-                 n_live=st.n_live - jnp.sum(do_cxl, dtype=jnp.int32))
-    return st, n_applied
-
-
 @jax.named_scope("drain")
 def _drain(st: S.SimState, tb: S.StaticTables, policy_id: jnp.ndarray,
            params: SimParams, const: tuple | None = None,
@@ -446,13 +384,6 @@ def _drain(st: S.SimState, tb: S.StaticTables, policy_id: jnp.ndarray,
     exactly one machine, which both matches the reference engine's
     sequential (seq-order) accumulation and drops the former O(N·M)
     ``queued_work`` reduction from every drain step.
-
-    With ``params.drain_k > 1`` each trip speculates up to K sequential
-    decisions in one batched dispatch and applies the maximal
-    sequentially-consistent prefix (``P.dispatch_k`` — bitwise the
-    single-step schedule), cutting trips from O(queue) to O(queue/K);
-    the loop remains bounded by the batch-queue population, now read
-    from the incremental ``n_batch`` counter.
 
     Tracing note: cancel rows are recorded *after* the loop by diffing
     the status column (one masked write per event, in task-id order)
@@ -475,28 +406,6 @@ def _drain(st: S.SimState, tb: S.StaticTables, policy_id: jnp.ndarray,
         const = (eet_nm, energy_nm)
     eet_nm = const[0]
 
-    if params.legacy_drain:
-        # PR-9-equivalent loop (the T12 bench baseline, never a
-        # production setting): every iteration re-runs the O(N·M)
-        # ``machine_available`` reduction inside ``build_view`` and the
-        # bound is the O(N) status scan — docs/engine_perf.md
-        bound_l = jnp.sum(st.tasks.status == S.IN_BATCH, dtype=jnp.int32)
-
-        def cond_l(c):
-            _, cont, iters = c
-            return cont & (iters < bound_l)
-
-        def body_l(c):
-            s, _, iters = c
-            dec = P.dispatch(policy_id, s, tb, params.lcap,
-                             params.cancel_infeasible, const, up, pparams,
-                             pallas=params.pallas)
-            return _apply_decision(s, dec), dec.task >= 0, iters + 1
-
-        st, _, _ = jax.lax.while_loop(
-            cond_l, body_l, (st, jnp.bool_(True), jnp.int32(0)))
-        return _drain_trace(st, trace, status_before)
-
     # one availability reduction per event, reusing the hoisted eet_nm
     # (the same floats machine_available gathers, summed in the same
     # task-id order)
@@ -506,13 +415,13 @@ def _drain(st: S.SimState, tb: S.StaticTables, policy_id: jnp.ndarray,
     in_mq = (st.tasks.status == S.IN_MQ)[:, None] & (
         st.tasks.machine[:, None] == jnp.arange(mach.mtype.shape[0])[None])
     avail0 = base + jnp.sum(jnp.where(in_mq, eet_nm, 0.0), axis=0)
-    k = max(1, int(params.drain_k))
 
     def cond(c):
         _, _, cont, iters = c
         return cont & (iters < bound)
 
-    def single_step(s, avail, iters):
+    def body(c):
+        s, avail, _, iters = c
         dec = P.dispatch(policy_id, s, tb, params.lcap,
                          params.cancel_infeasible, const, up, pparams,
                          pallas=params.pallas, avail=avail)
@@ -522,26 +431,6 @@ def _drain(st: S.SimState, tb: S.StaticTables, policy_id: jnp.ndarray,
         avail = jnp.where(
             m_oh, avail + eet_nm[jnp.clip(dec.task, 0, n - 1)], avail)
         return s, avail, dec.task >= 0, iters + 1
-
-    if k == 1:
-        def body(c):
-            s, avail, _, iters = c
-            return single_step(s, avail, iters)
-    else:
-        # K-wide trip: one batched dispatch constructs/validates up to K
-        # sequential decisions and applies the maximal prefix in one
-        # masked scatter.  (No shallow-queue fallback branch: under vmap
-        # a ``lax.cond`` batches into a select that executes BOTH
-        # branches every trip, so a hybrid costs the sum of the paths —
-        # measured in docs/engine_perf.md.)
-        def body(c):
-            s, avail, _, iters = c
-            dec, use, av = P.dispatch_k(policy_id, s, tb, params.lcap,
-                                        params.cancel_infeasible, k,
-                                        const, up, pparams,
-                                        pallas=params.pallas, avail=avail)
-            s, n_applied = _apply_decisions_k(s, dec, use)
-            return s, av, dec.task[0] >= 0, iters + n_applied
 
     st, _, _, _ = jax.lax.while_loop(cond, body, (st, avail0,
                                                   jnp.bool_(True),

@@ -411,317 +411,3 @@ def dispatch(policy_id: jnp.ndarray, state: S.SimState,
     dec = _switch_policy(policy_id, state, tables, view, params,
                          pallas=pallas)
     return _cancel_wrap(dec, view, state, cancel_infeasible)
-
-
-# --------------------------------------------------------------------------
-# K-way speculative dispatch (docs/engine_perf.md)
-# --------------------------------------------------------------------------
-# Task-order speculation: under the frozen pre-trip view, predict which
-# task each of the next K sequential drain steps would select.  Selection
-# keys that do not depend on earlier assignments in the trip (task id,
-# deadline, rank) make the prediction exact; Min-Min's key (best frozen
-# completion) is a heuristic guess that the prefix validation re-checks.
-_SPEC_ORDER: dict[str, str] = {
-    "fcfs": "head", "rr": "head", "met": "head", "mct": "head",
-    "ee_met": "head", "ee_mct": "head", "minmin": "minmin",
-    "maxmin": "maxmin", "edf_mct": "edf", "heft": "heft",
-}
-
-# Policies whose (task, machine) choice provably survives the prefix
-# corrections: with all prefix machines distinct, the winner's score cell
-# is untouched while every corrected cell weakly increases (IEEE
-# ``x + e >= x`` for ``e >= 0``) or gets masked to BIG, so the first-index
-# argmin/argmax tie-break is preserved.  ``rr`` (rr_ptr advances per map),
-# ``maxmin`` (argmax over weakly-increasing row minima can flip) and
-# learned/user-registered policies (opaque scoring) are conservative:
-# their prefix only extends past earlier candidates that were cancels,
-# which leave the view bitwise unchanged.
-_SPECULATIVE_SAFE = {"fcfs", "met", "mct", "ee_met", "ee_mct", "minmin",
-                     "edf_mct", "heft"}
-
-
-def _order_by_key(keys: jnp.ndarray, valid: jnp.ndarray,
-                  k: int) -> jnp.ndarray:
-    """First k task ids by (key, id) — stable argsort, so ties break to
-    the lowest id exactly like the sequential first-index argmin."""
-    masked = jnp.where(valid, keys, jnp.inf)
-    order = jnp.argsort(masked, stable=True)[:k]
-    order = jnp.where(valid[order], order, -1).astype(jnp.int32)
-    if order.shape[0] < k:           # fewer tasks than the drain width
-        order = jnp.pad(order, (0, k - order.shape[0]),
-                        constant_values=-1)
-    return order
-
-
-def _speculate_tasks(policy_id, state: S.SimState, tables: S.StaticTables,
-                     view: SchedView, k: int) -> jnp.ndarray:
-    """(k,) speculated task ids for the next k drain steps (-1 padded)."""
-    n = view.in_batch.shape[0]
-    ids = jnp.arange(n, dtype=jnp.float32)
-
-    def head(_):
-        # FIFO head order: the first k batch-queue ids
-        return _order_by_key(ids, view.in_batch, k)
-
-    def edf(_):
-        return _order_by_key(state.tasks.deadline, view.in_batch, k)
-
-    def by_rank(_):
-        return _order_by_key(-view.rank, view.in_batch, k)
-
-    def by_best(sign):
-        c = jnp.where(view.in_batch[:, None] & view.room[None, :],
-                      view.completion_full(), BIG)
-        return _order_by_key(sign * jnp.min(c, axis=1),
-                             view.in_batch & view.any_room, k)
-
-    kinds = {"head": head, "edf": edf, "heft": by_rank,
-             "minmin": lambda _: by_best(jnp.float32(1.0)),
-             "maxmin": lambda _: by_best(jnp.float32(-1.0))}
-    branches = [kinds[_SPEC_ORDER.get(name, "head")]
-                for name in POLICY_NAMES]
-    return jax.lax.switch(policy_id, branches, 0)
-
-
-# Policies whose j-th sequential drain decision is a *closed form* of
-# (avail, queue counts) after the first j-1 decisions: the task order is
-# a static key sort (id / deadline / rank — ties break to the lowest id,
-# exactly the sequential first-index argmin/argmax) and the machine rule
-# is the policy's own (M,) scoring expression.  These skip speculation
-# entirely: an unrolled O(M)-per-step scan *constructs* the K sequential
-# decisions bitwise (docs/engine_perf.md), so the prefix is always K.
-_SCAN_RULES: dict[str, tuple[str, str]] = {
-    # policy -> (task-order key, machine scoring rule)
-    "fcfs": ("head", "avail"),
-    "met": ("head", "eet"),
-    "mct": ("head", "mct"),
-    "ee_met": ("head", "energy"),
-    "ee_mct": ("head", "ee_mct"),
-    "edf_mct": ("edf", "mct"),
-    "heft": ("rank", "mct"),
-}
-
-
-def _scan_order(kind: str, state: S.SimState, view: SchedView,
-                k: int) -> jnp.ndarray:
-    if kind == "head":
-        key = jnp.arange(view.in_batch.shape[0], dtype=jnp.float32)
-    elif kind == "edf":
-        key = state.tasks.deadline
-    else:                                            # "rank" (HEFT)
-        key = -view.rank
-    return _order_by_key(key, view.in_batch, k)
-
-
-def _dispatch_k_scan(rule: str, order_kind: str, state: S.SimState,
-                     view: SchedView, lcap: int, cancel_infeasible,
-                     k: int, up: jnp.ndarray | None
-                     ) -> tuple[Decision, jnp.ndarray, jnp.ndarray]:
-    """Exact K-step sequential dispatch as an unrolled O(M)-per-step scan.
-
-    Carries (avail, per-machine map counts) through the K steps — the
-    same float adds in the same order as the sequential drain, the same
-    masked-argmin tie-breaks — while every O(N) term (order keys, row
-    gathers) is amortized over the whole trip.  Bitwise the single-step
-    schedule; also bitwise the kernel variants, whose exact-argmin
-    contract makes them interchangeable with the jnp expressions
-    (docs/kernels.md).
-    """
-    n = view.in_batch.shape[0]
-    n_m = view.room.shape[0]
-    order = _scan_order(order_kind, state, view, k)              # (k,)
-    tclip = jnp.clip(order, 0, n - 1)
-    eet_k = view.eet_nm[tclip]                                   # (k, M)
-    energy_k = view.energy_nm[tclip]                             # (k, M)
-    dl_k = state.tasks.deadline[tclip]                           # (k,)
-    ci = jnp.asarray(cancel_infeasible)
-    miota = jnp.arange(n_m)
-
-    def step(carry, xs):
-        avail, cnt = carry
-        t, eet_row, energy_row, dl = xs
-        room = (state.mq_count + cnt) < lcap
-        if up is not None:
-            room = room & up
-        any_room = room.any()
-        crow = avail + eet_row                       # completion_row(t)
-        if rule == "ee_mct":
-            feasible = (crow <= dl) & room
-            energy = jnp.where(feasible, energy_row, BIG)
-            fallback = jnp.where(room, crow, BIG)
-            scores = jnp.where(feasible.any(), energy, fallback)
-            m = jnp.argmin(scores).astype(jnp.int32)
-        else:
-            scores = {"avail": avail, "eet": eet_row,
-                      "energy": energy_row, "mct": crow}[rule]
-            m = jnp.argmin(jnp.where(room, scores, BIG)).astype(jnp.int32)
-        m = jnp.where(any_room, m, -1)
-        ok = (t >= 0) & any_room
-        task = jnp.where(ok, t, -1).astype(jnp.int32)
-        mach = jnp.where(ok, m, -1).astype(jnp.int32)
-        best = jnp.min(jnp.where(room, crow, BIG))   # _cancel_wrap
-        cancel = (task >= 0) & ci & (best > dl)
-        mapped = (task >= 0) & ~cancel
-        m_oh = (miota == mach) & mapped
-        avail = jnp.where(m_oh, avail + eet_row, avail)
-        return (avail, cnt + m_oh.astype(jnp.int32)), \
-            Decision(task, mach, cancel)
-
-    (avail_after, _), dec = jax.lax.scan(
-        step, (view.avail, jnp.zeros(n_m, jnp.int32)),
-        (order, eet_k, energy_k, dl_k), unroll=True)
-    # the queue and the room mask only shrink within a trip, so the
-    # first no-op is final: everything after it is a no-op too
-    use = jnp.cumsum((dec.task < 0).astype(jnp.int32)) == 0
-    return dec, use, avail_after
-
-
-def _dispatch_k_speculate(policy_id, state: S.SimState,
-                          tables: S.StaticTables, view: SchedView,
-                          lcap: int, cancel_infeasible, k: int,
-                          up: jnp.ndarray | None, params, pallas: bool
-                          ) -> tuple[Decision, jnp.ndarray, jnp.ndarray]:
-    """One speculative drain trip: up to k sequential decisions at once.
-
-    Builds k views of the frozen state — view j masks the j-1 earlier
-    speculated tasks out of ``in_batch`` — and runs ONE vmapped policy
-    switch over them.  A sequential-consistency prefix is then validated
-    candidate by candidate (see docs/engine_perf.md for the proof
-    obligations):
-
-      * the dispatched task equals the speculated one (the masked view
-        was built for exactly that queue),
-      * its machine is distinct from every earlier *mapped* machine in
-        the prefix (so the winner's score cell is untouched and each
-        corrected machine absorbs exactly one exact float add),
-      * the cancellation verdict re-derived under the corrected
-        avail/room equals the frozen one,
-      * conservative policies additionally require every earlier prefix
-        candidate to be a cancel (zero corrections -> views bitwise
-        equal to the true sequential state).
-
-    Candidate 0 is computed against the true state, so every trip
-    applies at least one decision and the fall-back to the single-step
-    path is just "prefix length 1".
-    """
-    n = view.in_batch.shape[0]
-    n_m = view.room.shape[0]
-    spec = _speculate_tasks(policy_id, state, tables, view, k)     # (k,)
-
-    # k masked queue views: candidate j sees the queue with speculated
-    # tasks 0..j-1 removed (exclusive running one-hot sum)
-    onehot = (spec[:, None] == jnp.arange(n)[None, :]) & \
-        (spec >= 0)[:, None]                                       # (k, N)
-    excl = jnp.cumsum(onehot, axis=0) - onehot                     # (k, N)
-    in_batch_k = view.in_batch[None, :] & (excl == 0)
-    head_k = jnp.where(in_batch_k.any(axis=1),
-                       jnp.argmax(in_batch_k, axis=1), -1).astype(jnp.int32)
-
-    def one(ib, hd):
-        v = view._replace(in_batch=ib, head=hd)
-        dec = _switch_policy(policy_id, state, tables, v, params,
-                             pallas=pallas)
-        return _cancel_wrap(dec, v, state, cancel_infeasible)
-
-    dec = jax.vmap(one)(in_batch_k, head_k)                        # (k,) each
-
-    task, mach, cancel = dec.task, dec.machine, dec.cancel
-    nonneg = task >= 0
-    mapped = nonneg & ~cancel
-    tclip = jnp.clip(task, 0, n - 1)
-    mclip = jnp.clip(mach, 0, n_m - 1)
-
-    # prefix corrections: per-machine map counts + expected-time adds
-    # accumulated over earlier *mapped* candidates (exclusive cumsum).
-    # Machine distinctness makes each corrected machine a single add, so
-    # ``avail + add`` is bitwise the sequential carry.
-    eet_nm = view.eet_nm
-    moh = (mclip[:, None] == jnp.arange(n_m)[None, :]) & \
-        mapped[:, None]                                            # (k, M)
-    cnt = jnp.cumsum(moh.astype(jnp.int32), axis=0) - moh          # (k, M)
-    add = jnp.where(moh, eet_nm[tclip], 0.0)
-    cum = jnp.cumsum(add, axis=0) - add                            # (k, M)
-    touched = cnt > 0
-    avail_k = jnp.where(touched, view.avail[None, :] + cum,
-                        view.avail[None, :])
-    room_k = (state.mq_count[None, :] + cnt) < lcap
-    if up is not None:
-        room_k = room_k & up[None, :]
-
-    # machine conflicts: candidate j colliding with an earlier mapped one
-    conflict = nonneg & (jnp.take_along_axis(
-        cnt, mclip[:, None], axis=1)[:, 0] > 0)
-
-    # cancellation verdict under the corrected avail/room
-    best_k = jnp.min(jnp.where(room_k, avail_k + eet_nm[tclip], BIG),
-                     axis=1)
-    cancel_true = nonneg & jnp.asarray(cancel_infeasible) & \
-        (best_k > state.tasks.deadline[tclip])
-    cancel_ok = cancel_true == cancel
-
-    # conservative policies: no mapped candidate may precede j
-    safe_tab = jnp.asarray([name in _SPECULATIVE_SAFE
-                            for name in POLICY_NAMES])
-    safe = safe_tab[policy_id]
-    prior_maps = jnp.cumsum(mapped.astype(jnp.int32)) - \
-        mapped.astype(jnp.int32)
-    ok = nonneg & (task == spec) & ~conflict & cancel_ok & \
-        (safe | (prior_maps == 0))
-    ok = ok.at[0].set(True)        # candidate 0 == the true decision
-    valid = jnp.cumsum(~ok) == 0   # maximal sequentially-consistent prefix
-    use = valid & nonneg
-    # carried avail after the applied prefix: machine distinctness means
-    # each used machine absorbs exactly one add — bitwise the sequential
-    # carry; untouched machines keep their exact bits
-    moh_used = moh & use[:, None]
-    addv = jnp.sum(jnp.where(moh_used, eet_nm[tclip], 0.0), axis=0)
-    avail_after = jnp.where(moh_used.any(axis=0), view.avail + addv,
-                            view.avail)
-    return dec, use, avail_after
-
-
-def dispatch_k(policy_id: jnp.ndarray, state: S.SimState,
-               tables: S.StaticTables, lcap: int,
-               cancel_infeasible: bool | jnp.ndarray, k: int,
-               const: tuple | None = None,
-               up: jnp.ndarray | None = None,
-               params=None, *, pallas: bool = False,
-               avail: jnp.ndarray | None = None
-               ) -> tuple[Decision, jnp.ndarray, jnp.ndarray]:
-    """One K-way drain trip: up to k sequential decisions in one call.
-
-    Two implementations, selected per policy (one ``lax.switch``):
-
-    * the head/EDF/rank-ordered family (``_SCAN_RULES``) *constructs*
-      the K sequential decisions exactly with an unrolled O(M)-per-step
-      scan (``_dispatch_k_scan``) — the prefix is always the full K;
-    * everything else (Min-Min/Max-Min's avail-dependent task choice,
-      ``rr``'s advancing pointer, learned/user-registered policies)
-      speculates under the frozen view and validates a
-      sequential-consistency prefix (``_dispatch_k_speculate``).
-
-    Either way the result is bitwise the single-step schedule.  Returns
-    the batched ``Decision`` ((k,) fields), the ``use`` prefix mask the
-    engine applies in one masked scatter (``engine._apply_decisions_k``),
-    and the carried machine-available vector after the applied prefix.
-    """
-    if params is None:
-        from repro.core import neural as NN
-        params = NN.default_params()
-    view = build_view(state, tables, lcap, const, up, avail)
-
-    def spec_branch(_):
-        return _dispatch_k_speculate(policy_id, state, tables, view,
-                                     lcap, cancel_infeasible, k, up,
-                                     params, pallas)
-
-    def scan_branch(order_kind, rule):
-        return lambda _: _dispatch_k_scan(rule, order_kind, state, view,
-                                          lcap, cancel_infeasible, k, up)
-
-    branches = [
-        scan_branch(*_SCAN_RULES[name]) if name in _SCAN_RULES
-        else spec_branch
-        for name in POLICY_NAMES
-    ]
-    return jax.lax.switch(policy_id, branches, 0)

@@ -87,9 +87,6 @@ class StreamParams(NamedTuple):
     metrics: bool = False         # in-jit histograms + SLO windows folded
     #                               into StreamAgg (docs/observability.md)
     metrics_spec: ME.MetricsSpec | None = None
-    drain_k: int = 1              # speculative drain width (the window
-    #                               runs the dense drain loop verbatim —
-    #                               docs/engine_perf.md)
 
     def sim_params(self) -> E.SimParams:
         """The dense-engine view (phases read lcap/qcap/cancel from it;
@@ -97,7 +94,7 @@ class StreamParams(NamedTuple):
         folds at retirement — so it is not forwarded here)."""
         return E.SimParams(lcap=self.lcap, qcap=self.qcap,
                            cancel_infeasible=self.cancel_infeasible,
-                           pallas=self.pallas, drain_k=self.drain_k)
+                           pallas=self.pallas)
 
 
 class TaskStream(NamedTuple):

@@ -7,6 +7,8 @@ randomized instances.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import pytest
 from _hyp import given, settings, st  # hypothesis optional (dev extra)
@@ -58,6 +60,18 @@ def test_engine_matches_ref_fixed(small_fleet, policy_id, pallas):
     eet, power, wl, mtype = small_fleet
     st_jax, ref = run_both(eet, power, wl, mtype, policy_id, pallas=pallas)
     assert_equivalent(st_jax, ref, f"policy={policy_id} pallas={pallas}")
+
+
+def test_deep_queue_drain_matches_ref(policy_id):
+    """Every task is in the batch queue at t=0, so the first drain makes
+    many decisions in one event: the carried machine-available vector
+    and the drain bound must still follow the oracle step for step."""
+    eet, power, wl, mtype = make_instance(11, 48, 6, rate=1e9)
+    wl = dataclasses.replace(wl, arrival=np.zeros_like(wl.arrival))
+    st_jax, ref = run_both(eet, power, wl, mtype, policy_id, lcap=12)
+    assert int(st_jax.n_events) == ref.n_events, \
+        f"event count diverged policy={policy_id}"
+    assert_equivalent(st_jax, ref, f"deep queue policy={policy_id}")
 
 
 @pytest.mark.pallas
